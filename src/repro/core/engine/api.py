@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import tracing
 from repro.core.counts import CountState
 from repro.core.engine import state as engine_state
 from repro.core.engine.backends import (iteration_vmap,
@@ -311,31 +312,36 @@ class ModelParallelLDA:
     # -- stepping ----------------------------------------------------------
     def _uniforms(self) -> jax.Array:
         b, r, cap = self.num_rounds, self.num_shards, self.capacity
-        u = self._rng.random((b, r, cap), np.float32)  # [rounds, rows, T]
-        return jnp.asarray(u)
+        with tracing.span(tracing.TRAIN_UNIFORMS):
+            # [rounds, rows, T]
+            return jnp.asarray(self._rng.random((b, r, cap), np.float32))
 
     def step(self) -> None:
         """Run one iteration (= S·M rounds, every token sampled once)."""
         from repro.core import faults
         faults.fire("step", f"iter:{self.iteration_count},engine:mp")
         u = self._uniforms()
-        if self.backend == "vmap":
-            self.state, errs = iteration_vmap(
-                self.state, u, self.doc, self.woff, self.mask,
-                self.alpha, jnp.float32(self.beta), jnp.float32(self.vbeta),
-                sampler_mode=self.sampler_mode, sync_ck=self.sync_ck,
-                data_parallel=self.data_parallel,
-                table_lifetime=self.table_lifetime,
-                track_error=self.track_error,
-                sampler_args=self.sampler_args)
-        else:
-            s = self.state
-            out = self._iter_fn(
-                s.cdk, s.ckt, s.block_id, s.ck_synced, s.ck_local, s.z,
-                jnp.swapaxes(u, 0, 1), self.doc, self.woff, self.mask,
-                self.alpha, jnp.float32(self.beta), jnp.float32(self.vbeta))
-            self.state = engine_state.MPState(*out[:6])
-            errs = out[6]
+        with tracing.span(tracing.TRAIN_DISPATCH,
+                          iteration=self.iteration_count):
+            if self.backend == "vmap":
+                self.state, errs = iteration_vmap(
+                    self.state, u, self.doc, self.woff, self.mask,
+                    self.alpha, jnp.float32(self.beta),
+                    jnp.float32(self.vbeta),
+                    sampler_mode=self.sampler_mode, sync_ck=self.sync_ck,
+                    data_parallel=self.data_parallel,
+                    table_lifetime=self.table_lifetime,
+                    track_error=self.track_error,
+                    sampler_args=self.sampler_args)
+            else:
+                s = self.state
+                out = self._iter_fn(
+                    s.cdk, s.ckt, s.block_id, s.ck_synced, s.ck_local, s.z,
+                    jnp.swapaxes(u, 0, 1), self.doc, self.woff, self.mask,
+                    self.alpha, jnp.float32(self.beta),
+                    jnp.float32(self.vbeta))
+                self.state = engine_state.MPState(*out[:6])
+                errs = out[6]
         self.round_errors = (np.asarray(errs).reshape(-1)
                              if self.track_error else np.zeros(0))
         self.iteration_count += 1

@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import tracing
 from repro.core import schedule as sched
 from repro.core.engine.rounds import (resolve_sampler,
                                       resolve_table_sampler, worker_round,
@@ -146,39 +147,49 @@ def iteration_vmap(state: MPState, u, doc, woff, mask, alpha, beta, vbeta,
         r_, s_, vb, k = state.ckt.shape
         # per-iteration doc tables from iteration-start cdk (DESIGN.md
         # §10): loop-invariant across all S·M rounds.
-        dtab = jax.vmap(build_doc_tables, in_axes=(0, None))(
-            state.cdk, alpha)
+        with jax.named_scope(tracing.DOC_TABLES):
+            dtab = jax.vmap(build_doc_tables, in_axes=(0, None))(
+                state.cdk, alpha)
 
         def round_step(carry, u_r, *, build):
             cdk, ckt, blk, ck_syn, ck_loc, z, ttab = carry
-            res_pre = ckt[:, 0]              # [R, Vb, K] round-start copies
-            res_blk = blk[:, 0]
+            with jax.named_scope(tracing.QUEUE):
+                res_pre = ckt[:, 0]          # [R, Vb, K] round-start copies
+                res_blk = blk[:, 0]
+                if not build:
+                    wtab = ttab[:, 0]        # the table that traveled in
             if build:
                 # first residency of this block this iteration: build its
                 # word table from the round-start copy (identical across
                 # replicas, so the D builds agree bitwise).
-                wtab = jax.vmap(build_word_tables, in_axes=(0, None))(
-                    res_pre, beta)
-            else:
-                wtab = ttab[:, 0]            # the table that traveled in
-            cdk, res_ckt, ck_loc, z = jax.vmap(
-                round_fn, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0,
-                                   None, None, None, 0, 0))(
-                cdk, res_pre, res_blk, ck_loc, z, u_r, doc, woff, mask,
-                alpha, beta, vbeta, wtab, dtab)
-            res_ckt = rotate(reconcile(res_ckt, res_pre))
-            res_blk = rotate(res_blk)
-            wtab = rotate(wtab)      # the table travels WITH its block
-            ckt = jnp.concatenate([ckt[:, 1:], res_ckt[:, None]], axis=1)
-            blk = jnp.concatenate([blk[:, 1:], res_blk[:, None]], axis=1)
-            ttab = jnp.concatenate([ttab[:, 1:], wtab[:, None]], axis=1)
-            ck_syn, ck_loc, err = sync_and_err(ck_syn, ck_loc)
+                with jax.named_scope(tracing.WORD_TABLES):
+                    wtab = jax.vmap(build_word_tables, in_axes=(0, None))(
+                        res_pre, beta)
+            with jax.named_scope(tracing.SAMPLE):
+                cdk, res_ckt, ck_loc, z = jax.vmap(
+                    round_fn, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0,
+                                       None, None, None, 0, 0))(
+                    cdk, res_pre, res_blk, ck_loc, z, u_r, doc, woff, mask,
+                    alpha, beta, vbeta, wtab, dtab)
+            with jax.named_scope(tracing.RECONCILE):
+                res_ckt = reconcile(res_ckt, res_pre)
+            with jax.named_scope(tracing.ROTATE):
+                res_ckt = rotate(res_ckt)
+                res_blk = rotate(res_blk)
+                wtab = rotate(wtab)  # the table travels WITH its block
+            with jax.named_scope(tracing.QUEUE):
+                ckt = jnp.concatenate([ckt[:, 1:], res_ckt[:, None]], axis=1)
+                blk = jnp.concatenate([blk[:, 1:], res_blk[:, None]], axis=1)
+                ttab = jnp.concatenate([ttab[:, 1:], wtab[:, None]], axis=1)
+            with jax.named_scope(tracing.CK_SYNC):
+                ck_syn, ck_loc, err = sync_and_err(ck_syn, ck_loc)
             return (cdk, ckt, blk, ck_syn, ck_loc, z, ttab), err
 
         # table queue mirroring the block queue; never read before its
         # slot is written (every block's table is built in rounds < S),
         # so the zero init is dead weight XLA can elide.
-        ttab0 = jnp.zeros((r_, s_, 3, vb, k), jnp.int32)
+        with jax.named_scope(tracing.QUEUE):
+            ttab0 = jnp.zeros((r_, s_, 3, vb, k), jnp.int32)
         carry = (state.cdk, state.ckt, state.block_id, state.ck_synced,
                  state.ck_local, state.z, ttab0)
         carry, errs_b = jax.lax.scan(partial(round_step, build=True),
@@ -192,18 +203,25 @@ def iteration_vmap(state: MPState, u, doc, woff, mask, alpha, beta, vbeta,
 
     def round_step(carry, u_r):
         cdk, ckt, blk, ck_syn, ck_loc, z = carry
-        res_pre = ckt[:, 0]                  # [R, Vb, K] round-start copies
-        res_blk = blk[:, 0]
-        cdk, res_ckt, ck_loc, z = jax.vmap(
-            round_fn, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0,
-                               None, None, None))(
-            cdk, res_pre, res_blk, ck_loc, z, u_r, doc, woff, mask,
-            alpha, beta, vbeta)
-        res_ckt = rotate(reconcile(res_ckt, res_pre))
-        res_blk = rotate(res_blk)
-        ckt = jnp.concatenate([ckt[:, 1:], res_ckt[:, None]], axis=1)
-        blk = jnp.concatenate([blk[:, 1:], res_blk[:, None]], axis=1)
-        ck_syn, ck_loc, err = sync_and_err(ck_syn, ck_loc)
+        with jax.named_scope(tracing.QUEUE):
+            res_pre = ckt[:, 0]              # [R, Vb, K] round-start copies
+            res_blk = blk[:, 0]
+        with jax.named_scope(tracing.SAMPLE):
+            cdk, res_ckt, ck_loc, z = jax.vmap(
+                round_fn, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0,
+                                   None, None, None))(
+                cdk, res_pre, res_blk, ck_loc, z, u_r, doc, woff, mask,
+                alpha, beta, vbeta)
+        with jax.named_scope(tracing.RECONCILE):
+            res_ckt = reconcile(res_ckt, res_pre)
+        with jax.named_scope(tracing.ROTATE):
+            res_ckt = rotate(res_ckt)
+            res_blk = rotate(res_blk)
+        with jax.named_scope(tracing.QUEUE):
+            ckt = jnp.concatenate([ckt[:, 1:], res_ckt[:, None]], axis=1)
+            blk = jnp.concatenate([blk[:, 1:], res_blk[:, None]], axis=1)
+        with jax.named_scope(tracing.CK_SYNC):
+            ck_syn, ck_loc, err = sync_and_err(ck_syn, ck_loc)
         return (cdk, ckt, blk, ck_syn, ck_loc, z), err
 
     carry = (state.cdk, state.ckt, state.block_id, state.ck_synced,
@@ -247,54 +265,69 @@ def make_shard_map_iteration(mesh: Mesh, axis: str, sampler_mode: str,
         s_ = ckt.shape[0]
         if tables:
             from repro.core.mh import build_doc_tables, build_word_tables
-            dtab = build_doc_tables(cdk, alpha)   # per-iteration, invariant
+            with jax.named_scope(tracing.DOC_TABLES):
+                dtab = build_doc_tables(cdk, alpha)   # per-iteration
 
         def round_step(carry, u_r, build=False):
             cdk, ckt, blk, ck_syn, ck_loc, z, ttab = carry
-            res_pre = ckt[0]
-            res_blk = blk[0]
-            if tables:
-                wtab = (build_word_tables(res_pre, beta) if build
-                        else ttab[0])
-                cdk, res_ckt, ck_loc, z = worker_round_tables(
-                    cdk, res_pre, res_blk, ck_loc, z, u_r, doc, woff,
-                    mask, alpha, beta, vbeta, wtab, dtab, sampler=sampler)
-            else:
-                cdk, res_ckt, ck_loc, z = worker_round(
-                    cdk, res_pre, res_blk, ck_loc, z, u_r, doc, woff,
-                    mask, alpha, beta, vbeta, sampler=sampler)
+            with jax.named_scope(tracing.QUEUE):
+                res_pre = ckt[0]
+                res_blk = blk[0]
+                if tables and not build:
+                    wtab = ttab[0]
+            if tables and build:
+                with jax.named_scope(tracing.WORD_TABLES):
+                    wtab = build_word_tables(res_pre, beta)
+            with jax.named_scope(tracing.SAMPLE):
+                if tables:
+                    cdk, res_ckt, ck_loc, z = worker_round_tables(
+                        cdk, res_pre, res_blk, ck_loc, z, u_r, doc, woff,
+                        mask, alpha, beta, vbeta, wtab, dtab,
+                        sampler=sampler)
+                else:
+                    cdk, res_ckt, ck_loc, z = worker_round(
+                        cdk, res_pre, res_blk, ck_loc, z, u_r, doc, woff,
+                        mask, alpha, beta, vbeta, sampler=sampler)
             if data_axis is not None:
                 # delta-psum reconciliation of the D replica copies of the
                 # resident block (DESIGN.md §8) — the only cross-replica
                 # traffic, one [Vb, K] all-reduce per round.
-                res_ckt = res_pre + jax.lax.psum(res_ckt - res_pre,
-                                                 data_axis)
+                with jax.named_scope(tracing.RECONCILE):
+                    res_ckt = res_pre + jax.lax.psum(res_ckt - res_pre,
+                                                     data_axis)
             # Algorithm 2 commit+request: ONLY the resident block travels —
             # per-round traffic stays one [Vb, K] block per worker (plus
             # its packed table under the iteration lifetime) no matter how
             # large S makes the total model.
-            res_ckt = jax.lax.ppermute(res_ckt, axis, perm)
-            res_blk = jax.lax.ppermute(res_blk, axis, perm)
-            ckt = jnp.concatenate([ckt[1:], res_ckt[None]], axis=0)
-            blk = jnp.concatenate([blk[1:], res_blk[None]], axis=0)
-            if tables:
-                wtab = jax.lax.ppermute(wtab, axis, perm)
-                ttab = jnp.concatenate([ttab[1:], wtab[None]], axis=0)
-            err = jnp.float32(0.0)
-            if sync_ck or track_error:
-                ck_true = ck_syn + jax.lax.psum(ck_loc - ck_syn, ck_axes)
-            if track_error:
-                n_tok = jnp.maximum(ck_true.sum(), 1).astype(jnp.float32)
-                err = jax.lax.pmean(
-                    jnp.abs(ck_loc - ck_true).sum().astype(jnp.float32),
-                    ck_axes) / n_tok
-            if sync_ck:
-                ck_loc = ck_true
-                ck_syn = ck_true
+            with jax.named_scope(tracing.ROTATE):
+                res_ckt = jax.lax.ppermute(res_ckt, axis, perm)
+                res_blk = jax.lax.ppermute(res_blk, axis, perm)
+                if tables:
+                    wtab = jax.lax.ppermute(wtab, axis, perm)
+            with jax.named_scope(tracing.QUEUE):
+                ckt = jnp.concatenate([ckt[1:], res_ckt[None]], axis=0)
+                blk = jnp.concatenate([blk[1:], res_blk[None]], axis=0)
+                if tables:
+                    ttab = jnp.concatenate([ttab[1:], wtab[None]], axis=0)
+            with jax.named_scope(tracing.CK_SYNC):
+                err = jnp.float32(0.0)
+                if sync_ck or track_error:
+                    ck_true = ck_syn + jax.lax.psum(ck_loc - ck_syn,
+                                                    ck_axes)
+                if track_error:
+                    n_tok = jnp.maximum(ck_true.sum(), 1).astype(
+                        jnp.float32)
+                    err = jax.lax.pmean(
+                        jnp.abs(ck_loc - ck_true).sum().astype(jnp.float32),
+                        ck_axes) / n_tok
+                if sync_ck:
+                    ck_loc = ck_true
+                    ck_syn = ck_true
             return (cdk, ckt, blk, ck_syn, ck_loc, z, ttab), err
 
-        ttab0 = (jnp.zeros((s_, 3) + ckt.shape[1:], jnp.int32) if tables
-                 else jnp.zeros((), jnp.int32))
+        with jax.named_scope(tracing.QUEUE):
+            ttab0 = (jnp.zeros((s_, 3) + ckt.shape[1:], jnp.int32)
+                     if tables else jnp.zeros((), jnp.int32))
         carry = (cdk, ckt, blk, ck_syn, ck_loc, z, ttab0)
         if tables:
             # first S rounds build each block's table at its first

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.mh import DEFAULT_MH_CYCLES, build_doc_tables
 from repro.core.sampler import sample_from_mass
 
@@ -468,6 +469,7 @@ class FoldInResult:
     cdk: np.ndarray      # [Q, K] int32 inferred doc-topic counts
     z: np.ndarray        # [Q, T] int32 final assignments (block layout)
     theta: np.ndarray    # [Q, K] f64 posterior-mean mixtures
+    h2d_bytes: int = 0   # bytes of host arrays the call copied to the device
 
 
 def fold_in(snapshot: ModelSnapshot, word: np.ndarray, mask: np.ndarray,
@@ -485,6 +487,11 @@ def fold_in(snapshot: ModelSnapshot, word: np.ndarray, mask: np.ndarray,
     arrays.  ``sampler`` is ``"scan"`` (exact CGS) or any table-capable
     registry sampler (``"mh"``/``"mh_pallas"`` — the MH pair draws
     identically, as in training).
+
+    Every operand of the jitted sweeps is converted to a device array in
+    one place; ``h2d_bytes`` counts the host arrays among them (snapshot
+    arrays included, on every call), and arrays already on the device
+    count 0.
     """
     word = np.asarray(word, np.int32)
     mask = np.asarray(mask, bool)
@@ -500,24 +507,19 @@ def fold_in(snapshot: ModelSnapshot, word: np.ndarray, mask: np.ndarray,
         u = rng.random((num_sweeps, *word.shape), np.float32)
     u = np.asarray(u, np.float32)
     cdk0 = init_query_cdk(z0, mask, k)
-    alpha = jnp.asarray(snapshot.alpha)
 
     if sampler == "scan":
-        cdk, z = _fold_in_scan_sweeps(
-            jnp.asarray(cdk0), jnp.asarray(snapshot.word_term()),
-            jnp.asarray(word), jnp.asarray(z0), jnp.asarray(mask),
-            jnp.asarray(u), alpha)
+        sweeps = _fold_in_scan_sweeps
+        operands = (cdk0, snapshot.word_term(), word, z0, mask, u,
+                    snapshot.alpha)
     elif sampler in ("sparse", "sparse_pallas"):
         # one jnp implementation serves both names: with the model frozen
         # there is no per-round lane extraction to fuse, so the serving
         # path has no separate kernel form (the alias keeps `--sampler`
         # choices symmetric between training and inference).
         xcs, sx = snapshot.sparse_state()
-        cdk, z = _fold_in_sparse_sweeps(
-            jnp.asarray(cdk0), jnp.asarray(snapshot.word_term()),
-            jnp.asarray(xcs), jnp.asarray(sx), jnp.asarray(word),
-            jnp.asarray(z0), jnp.asarray(mask), jnp.asarray(u),
-            dcap=min(k, word.shape[1]))
+        sweeps = partial(_fold_in_sparse_sweeps, dcap=min(k, word.shape[1]))
+        operands = (cdk0, snapshot.word_term(), xcs, sx, word, z0, mask, u)
     else:
         from repro.core.engine.rounds import table_capable
         if not table_capable(sampler):
@@ -525,14 +527,22 @@ def fold_in(snapshot: ModelSnapshot, word: np.ndarray, mask: np.ndarray,
                 f"unknown fold-in sampler {sampler!r}; expected 'scan', "
                 "'sparse'/'sparse_pallas', or a table-capable registry "
                 "sampler (the MH family)")
-        cdk, z = _fold_in_mh_sweeps(
-            jnp.asarray(cdk0), jnp.asarray(snapshot.ckt),
-            jnp.asarray(snapshot.ck), jnp.asarray(snapshot.ensure_tables()),
-            jnp.asarray(word), jnp.asarray(z0), jnp.asarray(mask),
-            jnp.asarray(u), alpha, jnp.float32(snapshot.beta),
-            jnp.float32(snapshot.vbeta), sampler_mode=sampler,
-            num_cycles=num_cycles)
+        sweeps = partial(_fold_in_mh_sweeps, sampler_mode=sampler,
+                         num_cycles=num_cycles)
+        operands = (cdk0, snapshot.ckt, snapshot.ck,
+                    snapshot.ensure_tables(), word, z0, mask, u,
+                    snapshot.alpha, np.float32(snapshot.beta),
+                    np.float32(snapshot.vbeta))
 
-    cdk = np.asarray(cdk)
-    return FoldInResult(cdk=cdk, z=np.asarray(z),
-                        theta=theta_from_cdk(cdk, snapshot.alpha))
+    h2d = sum(np.asarray(x).nbytes for x in operands
+              if not isinstance(x, jax.Array))
+    with tracing.span(tracing.FOLDIN_UPLOAD, bytes=h2d):
+        operands = [jnp.asarray(x) for x in operands]
+    with tracing.span(tracing.FOLDIN_RUN):
+        cdk, z = sweeps(*operands)
+    with tracing.span(tracing.FOLDIN_FETCH):
+        cdk = np.asarray(cdk)
+        z = np.asarray(z)
+    with tracing.span(tracing.FOLDIN_THETA):
+        theta = theta_from_cdk(cdk, snapshot.alpha)
+    return FoldInResult(cdk=cdk, z=z, theta=theta, h2d_bytes=int(h2d))

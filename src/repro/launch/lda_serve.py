@@ -266,6 +266,14 @@ def main() -> None:
           f"rejections {summary['rejections'] or 'none'}")
     print(f"epochs served: {summary['epochs']} over "
           f"{sched.swaps} swap(s); dropped {summary['dropped']}")
+    log = list(sched.batch_log)
+    if log:
+        h2d_mb = sum(b["h2d_bytes"] for b in log) / len(log) / 1e6
+        wait_ms = (1e3 * sum(b["wait_s"] for b in log)
+                   / sum(b["size"] for b in log))
+        print(f"host path: {h2d_mb:,.1f} MB host->device per batch, "
+              f"queue wait {wait_ms:.2f} ms per request "
+              f"(last {len(log)} batches)")
     st = sched.stats()
     print(f"faults: {st['faults']}")
     print("breakers: " + "  ".join(

@@ -65,6 +65,7 @@ from typing import Deque, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core import faults
 from repro.core.infer import DEFAULT_FOLD_IN_SWEEPS, ModelSnapshot
 from repro.data.integrity import CorruptArtifactError
@@ -240,6 +241,10 @@ BREAKER_CLOSED = "closed"
 BREAKER_OPEN = "open"
 BREAKER_HALF_OPEN = "half_open"
 
+# ``batch_log`` keeps the newest this many dispatched batches: bounded for
+# a long-running server, and more than a benchmark window dispatches
+BATCH_LOG_LEN = 65536
+
 
 @dataclasses.dataclass
 class ReplicaHealth:
@@ -374,7 +379,10 @@ class ServingScheduler:
         self._rr = 0                        # round-robin batch counter
         self._next_id = 0
         self.results: Dict[int, Response] = {}
-        self.batch_log: List[dict] = []
+        # the newest BATCH_LOG_LEN dispatched batches; ``batches`` counts
+        # them all
+        self.batch_log: Deque[dict] = deque(maxlen=BATCH_LOG_LEN)
+        self.batches = 0
         self.submitted = 0
         self.admitted = 0
         self.served = 0
@@ -633,34 +641,14 @@ class ServingScheduler:
         epoch = batch[0].epoch
         assert all(p.epoch == epoch for p in batch)   # one snapshot/batch
         servers = self._servers[epoch]
-        fp = self._fp[epoch]
         docs = [p.canon for p in batch]
-        draws = [request_draws(self.seed, fp, p.digest, p.canon.size,
-                               servers[0].snapshot.num_topics,
-                               self.num_sweeps)
-                 for p in batch]
-        avail = self._available_replicas(t_dispatch)
-        start = self._rr % max(len(avail), 1)
-        self._rr += 1
-        candidates = avail[start:] + avail[:start]
-        theta = None
-        replica = -1
-        for attempt, rid in enumerate(candidates):
-            if attempt > 0:
-                self.retries += 1
-            now = self.clock.now()
-            try:
-                self._fire_replica(rid, epoch)
-                theta = servers[rid].infer_with_draws(
-                    docs, [d[0] for d in draws], [d[1] for d in draws])
-            except Exception:
-                self.replica_failures += 1
-                self.health[rid].record_failure(now,
-                                                self.breaker_threshold)
-                continue
-            self.health[rid].record_success()
-            replica = rid
-            break
+        bucket = servers[0].bucket_shape(docs)
+        with tracing.span(tracing.SERVE_BATCH, size=len(batch),
+                          bucket=bucket, epoch=epoch,
+                          first=batch[0].req_id) as span:
+            h2d_before = sum(s.h2d_bytes for s in servers)
+            theta, replica = self._dispatch(batch, docs, t_dispatch)
+            span.set_metadata(replica=replica)
         if theta is None:
             # every available replica refused this batch: charge each
             # request's retry budget and requeue the survivors in order
@@ -675,10 +663,14 @@ class ServingScheduler:
             self._queue.extendleft(reversed(survivors))
             return [], False
         t_finish = self.clock.now()
+        self.batches += 1
         self.batch_log.append({
             "epoch": epoch, "size": len(batch), "replica": replica,
-            "bucket": servers[replica].bucket_shape(docs),
-            "t_dispatch": t_dispatch})
+            "bucket": bucket, "t_dispatch": t_dispatch,
+            "h2d_bytes": sum(s.h2d_bytes for s in servers) - h2d_before,
+            "wait_s": sum(t_dispatch - p.t_arrival for p in batch),
+            "t_finish": t_finish})
+        fp = self._fp[epoch]
         responses = []
         for i, p in enumerate(batch):
             resp = Response(p.req_id, "ok", theta=theta[i], epoch=epoch,
@@ -691,6 +683,40 @@ class ServingScheduler:
             if epoch == self.epoch:      # never cache for a dead epoch
                 self.cache.put(p.digest, p.canon, theta[i])
         return responses, True
+
+    def _dispatch(self, batch: List[_Pending], docs: List[np.ndarray],
+                  t_dispatch: float) -> "tuple[Optional[np.ndarray], int]":
+        """Draw the batch's randomness and try the available replicas in
+        round-robin order; returns ``(theta, replica)``, or ``(None, -1)``
+        when every one of them failed."""
+        epoch = batch[0].epoch
+        servers = self._servers[epoch]
+        fp = self._fp[epoch]
+        with tracing.span(tracing.SERVE_DRAWS):
+            draws = [request_draws(self.seed, fp, p.digest, p.canon.size,
+                                   servers[0].snapshot.num_topics,
+                                   self.num_sweeps)
+                     for p in batch]
+        avail = self._available_replicas(t_dispatch)
+        start = self._rr % max(len(avail), 1)
+        self._rr += 1
+        candidates = avail[start:] + avail[:start]
+        for attempt, rid in enumerate(candidates):
+            if attempt > 0:
+                self.retries += 1
+            now = self.clock.now()
+            try:
+                self._fire_replica(rid, epoch)
+                theta = servers[rid].infer_with_draws(
+                    docs, [d[0] for d in draws], [d[1] for d in draws])
+            except Exception:
+                self.replica_failures += 1
+                self.health[rid].record_failure(now,
+                                                self.breaker_threshold)
+                continue
+            self.health[rid].record_success()
+            return theta, rid
+        return None, -1
 
     # -- observability -----------------------------------------------------
     def ok_responses(self) -> List[Response]:
@@ -723,7 +749,7 @@ class ServingScheduler:
             "served": self.served,
             "dropped": self.dropped(),
             "queue_depth": len(self._queue),
-            "batches": len(self.batch_log),
+            "batches": self.batches,
             "swaps": self.swaps,
             "rejections": dict(self.rejections),
             "cache": {"hits": self.cache.hits, "misses": self.cache.misses,

@@ -23,6 +23,7 @@ from typing import Dict, Sequence, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core.infer import (DEFAULT_FOLD_IN_SWEEPS, ModelSnapshot,
                               fold_in, pack_queries)
 from repro.core.likelihood import doc_completion_perplexity
@@ -65,6 +66,7 @@ class TopicInferenceServer:
         # (tests assert reuse; ops would watch for bucket explosion)
         self.bucket_calls: Dict[Tuple[int, int], int] = {}
         self.docs_served = 0
+        self.h2d_bytes = 0                # host -> device bytes, all calls
 
     def bucket_shape(self, docs: Sequence[Sequence[int]]
                      ) -> Tuple[int, int]:
@@ -81,12 +83,12 @@ class TopicInferenceServer:
         if not len(docs):
             return np.zeros((0, self.snapshot.num_topics), np.float64)
         qb, tb = self.bucket_shape(docs)
-        word, mask = pack_queries(docs, t_pad=tb, q_pad=qb)
+        with tracing.span(tracing.FOLDIN_PACK):
+            word, mask = pack_queries(docs, t_pad=tb, q_pad=qb)
         res = fold_in(self.snapshot, word, mask,
                       num_sweeps=self.num_sweeps, sampler=self.sampler,
                       rng=self._rng)
-        self.bucket_calls[(qb, tb)] = self.bucket_calls.get((qb, tb), 0) + 1
-        self.docs_served += len(docs)
+        self._count(qb, tb, len(docs), res)
         return res.theta[:len(docs)]
 
     def infer_with_draws(self, docs: Sequence[Sequence[int]],
@@ -113,25 +115,30 @@ class TopicInferenceServer:
                 f"need one z0/u row per doc: {len(docs)} docs vs "
                 f"{len(z0_rows)}/{len(u_rows)} rows")
         qb, tb = self.bucket_shape(docs)
-        word, mask = pack_queries(docs, t_pad=tb, q_pad=qb)
-        z0 = np.zeros((qb, tb), np.int32)
-        u = np.zeros((self.num_sweeps, qb, tb), np.float32)
-        for i, d in enumerate(docs):
-            n = len(d)
-            z_r = np.asarray(z0_rows[i], np.int32)
-            u_r = np.asarray(u_rows[i], np.float32)
-            if z_r.shape != (n,) or u_r.shape != (self.num_sweeps, n):
-                raise ValueError(
-                    f"doc {i}: draws must be z0 [{n}] / u "
-                    f"[{self.num_sweeps}, {n}], got {z_r.shape} / "
-                    f"{u_r.shape}")
-            z0[i, :n] = z_r
-            u[:, i, :n] = u_r
+        with tracing.span(tracing.FOLDIN_PACK):
+            word, mask = pack_queries(docs, t_pad=tb, q_pad=qb)
+            z0 = np.zeros((qb, tb), np.int32)
+            u = np.zeros((self.num_sweeps, qb, tb), np.float32)
+            for i, d in enumerate(docs):
+                n = len(d)
+                z_r = np.asarray(z0_rows[i], np.int32)
+                u_r = np.asarray(u_rows[i], np.float32)
+                if z_r.shape != (n,) or u_r.shape != (self.num_sweeps, n):
+                    raise ValueError(
+                        f"doc {i}: draws must be z0 [{n}] / u "
+                        f"[{self.num_sweeps}, {n}], got {z_r.shape} / "
+                        f"{u_r.shape}")
+                z0[i, :n] = z_r
+                u[:, i, :n] = u_r
         res = fold_in(self.snapshot, word, mask, num_sweeps=self.num_sweeps,
                       sampler=self.sampler, z0=z0, u=u)
-        self.bucket_calls[(qb, tb)] = self.bucket_calls.get((qb, tb), 0) + 1
-        self.docs_served += len(docs)
+        self._count(qb, tb, len(docs), res)
         return res.theta[:len(docs)]
+
+    def _count(self, qb: int, tb: int, n_docs: int, res) -> None:
+        self.bucket_calls[(qb, tb)] = self.bucket_calls.get((qb, tb), 0) + 1
+        self.docs_served += n_docs
+        self.h2d_bytes += res.h2d_bytes
 
     def infer_one(self, words: Sequence[int]) -> np.ndarray:
         """Single-doc convenience: word ids -> ``θ̂`` [K]."""
