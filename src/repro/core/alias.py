@@ -5,9 +5,10 @@ O(1) amortized by replacing the exact inverse-CDF draw over K topics with
 a Metropolis–Hastings proposal drawn from an *alias table*: per-topic
 arrays such that a single uniform yields an exact sample of the table's
 distribution in two lookups (Walker 1977; Vose 1991).  Construction is
-O(K), done once per *block* per round and amortized over every token that
-samples against the block — the same build-once/consume-many shape as the
-paper's eq.-(3) word-major cache.
+one row-wise merge (O(K log K) per row), done once per table lifetime
+and amortized over every token that samples against the table — the
+same build-once/consume-many shape as the paper's eq.-(3) word-major
+cache.
 
 **Determinism is load-bearing.**  The same table must be built bit-for-bit
 by every compilation of the sampler — the vmap engine, the shard_map
@@ -22,9 +23,10 @@ device builder therefore works on a fixed-point integer grid:
   int32 arithmetic (counts are ints; the prior is quantized once);
 * the per-cell capacity is the INTEGER row total ``ΣW`` (masses are kept
   scaled by K, so no division ever happens);
-* every fp value that feeds a decision is produced by a single IEEE op
-  on integer-derived operands (one convert, one multiply, one add/sub) —
-  nothing XLA can reassociate, recompute, or turn into a reciprocal.
+* every decision of the build is an integer comparison, and the one fp
+  value that feeds a draw decision (``frac·U < cut``) is a single IEEE
+  multiply of integer-derived operands — nothing XLA can reassociate,
+  recompute, or turn into a reciprocal.
 
 Quantizing the prior perturbs only the *proposal*; the MH acceptance
 (`core/mh.py`) evaluates the proposal mass from the same ``W`` grid and
@@ -38,11 +40,44 @@ cell ``j`` yields ``j`` when ``frac·U < cut[j]`` else ``alias[j]``, where
 picks the cell, the fractional part is the within-cell threshold (the
 standard single-uniform alias trick).
 
-:func:`build_alias_int_np` mirrors the device builder op-for-op in
-numpy (same f32 single-op chains, same LIFO stack discipline) and is
-asserted bit-equal by tests; :func:`build_alias_np` is the classic
-float construction kept as the property-test reference for the pairing
-logic itself.
+**Construction: the sweep, in closed form.**  The pairing follows the
+sweeping construction of Hübschle-Schneider & Sanders ("Parallel Weighted
+Random Sampling", ESA 2019 / ACM TOMS 2022): lights (``m_i = K·W_i < U``)
+in ascending topic order each take their deficit ``d_i = U − m_i`` from
+the current heavy, heavies in ascending topic order; a heavy drawn below
+``U`` becomes a cell of its own that spills onto the next heavy.  Which
+heavy serves which light follows from prefix sums alone — ``Dex``/``Din``,
+the exclusive/inclusive sums of the deficits over the row's lights, and
+``Ein``, the inclusive sum of the surpluses ``e_j = m_j − U`` over its
+heavies:
+
+* a light gets ``cut = m_i`` and ``alias`` = the first heavy with
+  ``Ein_j > Dex_i``;
+* a heavy other than the row's last, with ``i*`` the last light whose
+  ``Dex < Ein_j``, overshoots by ``o_j = Din_{i*} − Ein_j``; if ``o_j >
+  0`` it gets ``cut = U − o_j`` and the next heavy as alias;
+* every other cell is full.
+
+:func:`build_alias_int_rows` finds both lookups with one row-wise sort
+that merges the two monotone sequences — no loop over K and no scatter.
+
+**Exact in integers.**  The prefix sums reach ``K·U``, past int32 for a
+heavy word (about 4·10¹¹ for this repo's PubMed corpus), so the device
+keeps them as two int32 words (``hi·2¹⁶ + lo``, each word summed on its
+own) and compares them lexicographically; the overshoot, which lies in
+``[0, U)``, is taken from a plain int32 sum that wraps mod 2³² and is
+exact there.  Every decision is an integer comparison and ``cut`` is one
+f32 convert of an int32 in ``[0, U]``, so the bits cannot depend on how
+a program fuses or orders its sums — the reason the float prefix sum of
+the textbook form is never used (DESIGN.md §9 rule 1).  The two sorts
+feed no loop inside the builder; DESIGN.md §9 rule 2 is kept by the
+cross-backend bitwise tests (vmap == shard_map == host replay), which
+run the tables through the samplers' loops.
+
+:func:`build_alias_int_np` mirrors the closed form in int64 numpy and is
+asserted bit-equal by tests; :func:`build_alias_np` is the classic float
+construction kept as the property-test reference for the pairing logic
+itself.
 """
 from __future__ import annotations
 
@@ -154,95 +189,90 @@ def int_masses_np(counts: np.ndarray, prior: np.ndarray) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Device (JAX) construction — integer-exact decisions, fixed-shape scan
+# Device (JAX) construction — the sweep in closed form, exact integers
 # ---------------------------------------------------------------------------
+
+_LO_BITS = 16                 # two-word integers: value = hi·2¹⁶ + lo
+_LO_MASK = (1 << _LO_BITS) - 1
+
+
+def _prefix2(hi: jax.Array, lo: jax.Array, exclusive: bool = False
+             ) -> Tuple[jax.Array, jax.Array]:
+    """Prefix sums along K of two-word values ``hi·2¹⁶ + lo`` (``lo`` in
+    ``[0, 2¹⁶)``), normalized so the low word is again in ``[0, 2¹⁶)``.
+
+    Each word is summed in int32 on its own: the low words stay under
+    ``K·2¹⁶`` and the high words under the row's total over 2¹⁶, so
+    neither overflows while ``K < 2¹⁵`` (checked by the builder)."""
+    s_hi = jax.lax.cumsum(hi, axis=1)
+    s_lo = jax.lax.cumsum(lo, axis=1)
+    if exclusive:
+        s_hi, s_lo = s_hi - hi, s_lo - lo
+    return s_hi + (s_lo >> _LO_BITS), s_lo & _LO_MASK
+
 
 @jax.jit
 def build_alias_int_rows(w: jax.Array
                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Vose tables from integer masses ``w`` [N, K] -> (cut, alias, U).
+    """Alias tables from integer masses ``w`` [N, K] -> (cut, alias, U).
 
-    Works in masses-scaled-by-K units: ``m_i = f32(w_i)·K`` and the
-    per-cell capacity is ``U = f32(Σw)`` (an exact int32 reduction, so U
-    is bit-identical in every program).  Each scan step pops one small
-    and one large cell per row (a no-op once either stack empties) — at
-    most K-1 pairings, so K steps suffice.  Every fp decision input is
-    one IEEE op away from integers; see the module docstring for why
-    that is the point.
+    The sweep construction of the module docstring, every row at once.
+    With ``m_i = K·w_i`` and the integer capacity ``U = Σw``:
 
-    Layout choices are all about making the K-step loop cheap and
-    shard_map-safe:
+    * deficits ``d_i = U − m_i`` of the lights and surpluses ``e_j = m_j −
+      U`` of the heavies get exact two-word prefix sums in topic order
+      (``Dex`` exclusive over lights, ``Ein`` inclusive over heavies);
+    * ONE row-wise ``lax.sort`` merges the two monotone sequences: a light
+      keyed by ``Dex``, a heavy by ``Ein``, heavies first on a tie and
+      topics in order, so a light's alias is the next heavy after it and
+      a heavy is preceded by exactly the lights with ``Dex < Ein``;
+    * in merged order one cumsum of ``v = m − U`` (``−d`` for a light,
+      ``e`` for a heavy) gives each heavy's overshoot ``o = Din − Ein`` as
+      its negation — the true value lies in ``[0, U)``, so the int32 sum,
+      which wraps mod 2³², is exact there;
+    * a reverse ``cummin`` names the next heavy, and a second sort by
+      topic puts ``cut``/``alias`` back in topic order.
 
-    * rows are HAND-BATCHED on flat ``[N·K]`` buffers with precomputed
-      row offsets, so each step issues ONE 1-D gather/scatter of N
-      elements instead of XLA's far slower batched-scatter form;
-    * both stacks share one packed per-row buffer — smalls grow from the
-      left (top at ``ns-1``), larges from the right (top at ``K-nl``,
-      deeper = smaller index), so pops take the highest index first,
-      matching the numpy mirror's list discipline; ``ns+nl`` shrinks by
-      one per pairing, so the regions never collide;
-    * stacks are initialized with cumsum positions + scatter, NOT
-      argsort: feeding a sort HLO into a rolled loop miscompiles on the
-      multi-device XLA CPU runtime the shard_map backend tests run under
-      (non-zero devices read corrupted stacks);
-    * no-op steps write NOTHING (sentinel index + ``mode="drop"``) and
-      guards apply to the written element, never the whole array — a
-      ``where(cont, arr.at[i].set(v), arr)`` select is O(K) per step and
-      would turn the O(K) build into O(K²) per row;
-    * the loop carries only ``(m, stack)`` — cut/alias are emitted as
-      scan outputs and scattered once afterwards (each cell is popped as
-      a small at most once).
+    No loop, no gather, no scatter: two sorts, prefix scans and
+    elementwise ops along K.  ``cut`` is one f32 convert of an exact
+    int32 in ``[0, U]``.
     """
     n, k = w.shape
-    nk = n * k
+    if k >= 1 << (31 - _LO_BITS):
+        raise ValueError(f"K = {k} overflows the builder's two-word "
+                         f"prefix sums (K < {1 << (31 - _LO_BITS)})")
     w = w.astype(jnp.int32)
-    base = jnp.arange(n, dtype=jnp.int32) * k
-    u_cap = w.sum(axis=1).astype(jnp.float32)    # [N] exact, order-free
-    m = (w.astype(jnp.float32) * jnp.float32(k)).reshape(nk)
-    small_mask = m.reshape(n, k) < u_cap[:, None]
-    idx = jnp.broadcast_to(jnp.arange(k, dtype=jnp.int32), (n, k))
-    smask = small_mask.astype(jnp.int32)
-    spos = jnp.cumsum(smask, axis=1) - 1
-    lpos = jnp.cumsum(1 - smask, axis=1) - 1
-    sentinel = nk
-    stack = jnp.zeros(nk, jnp.int32) \
-        .at[jnp.where(small_mask, base[:, None] + spos,
-                      sentinel).reshape(nk)].set(idx.reshape(nk),
-                                                 mode="drop") \
-        .at[jnp.where(small_mask, sentinel,
-                      base[:, None] + (k - 1) - lpos).reshape(nk)].set(
-            idx.reshape(nk), mode="drop")
-    ns = smask.sum(axis=1)
-    nl = k - ns
-
-    def step(carry, _):
-        m, stack, ns, nl = carry
-        cont = (ns > 0) & (nl > 0)
-        s = stack[base + jnp.maximum(ns - 1, 0)]
-        lg = stack[base + jnp.minimum(k - nl, k - 1)]
-        m_s = m[base + s]
-        rem = (m[base + lg] + m_s) - u_cap       # single add, single sub
-        m = m.at[jnp.where(cont, base + lg, sentinel)].set(rem,
-                                                           mode="drop")
-        to_small = rem < u_cap
-        ns2, nl2 = ns - 1, nl - 1
-        # push lg: slot ns2 if it went small, else new large top K-nl2-1
-        i_push = jnp.where(to_small, ns2, k - nl2 - 1)
-        stack = stack.at[jnp.where(cont, base + i_push, sentinel)].set(
-            lg, mode="drop")
-        ns3 = jnp.where(cont, jnp.where(to_small, ns2 + 1, ns2), ns)
-        nl3 = jnp.where(cont, jnp.where(to_small, nl2, nl2 + 1), nl)
-        out = (jnp.where(cont, base + s, sentinel), m_s, lg)
-        return (m, stack, ns3, nl3), out
-
-    carry = (m, stack, ns, nl)
-    _, (s_seq, ms_seq, lg_seq) = jax.lax.scan(step, carry, None, length=k)
-    # full / leftover cells: cut = U, alias = self; popped smalls overwrite
-    cut = (jnp.ones((n, k), jnp.float32) * u_cap[:, None]).reshape(nk)
-    cut = cut.at[s_seq.reshape(-1)].set(ms_seq.reshape(-1), mode="drop")
-    alias = idx.reshape(nk).at[s_seq.reshape(-1)].set(lg_seq.reshape(-1),
-                                                      mode="drop")
-    return cut.reshape(n, k), alias.reshape(n, k), u_cap
+    u = w.sum(axis=1)                                  # [N] exact int32
+    uc = u[:, None]
+    light = w <= (u - 1)[:, None] // k                 # ⇔ K·w < U
+    d = jnp.where(light, uc - w * k, 0)                # exact: K·w < U
+    # e = K·w − U of a heavy, in two words (K·w may pass 2³¹)
+    e_lo = (w & _LO_MASK) * k - (uc & _LO_MASK)
+    e_hi = (w >> _LO_BITS) * k - (uc >> _LO_BITS) + (e_lo >> _LO_BITS)
+    e_hi = jnp.where(light, 0, e_hi)
+    e_lo = jnp.where(light, 0, e_lo & _LO_MASK)
+    dex_hi, dex_lo = _prefix2(d >> _LO_BITS, d & _LO_MASK, exclusive=True)
+    ein_hi, ein_lo = _prefix2(e_hi, e_lo)
+    topic = jax.lax.broadcasted_iota(jnp.int32, (n, k), 1)
+    _, key_lo, s_topic, s_v = jax.lax.sort(
+        (jnp.where(light, dex_hi, ein_hi),
+         jnp.where(light, dex_lo * 2 + 1, ein_lo * 2),   # heavy first
+         topic, w * k - uc),                              # v, mod 2³²
+        dimension=1, num_keys=3)
+    s_light = (key_lo & 1) == 1
+    overshoot = -jax.lax.cumsum(s_v, axis=1)         # exact at heavies
+    next_heavy = jax.lax.cummin(jnp.where(s_light, k, s_topic), axis=1,
+                                reverse=True)
+    after = jnp.concatenate(
+        [next_heavy[:, 1:], jnp.full((n, 1), k, jnp.int32)], axis=1)
+    spill = ~s_light & (overshoot > 0)
+    s_cut = jnp.where(s_light, uc + s_v,
+                      jnp.where(spill, uc - overshoot, uc))
+    s_alias = jnp.where(s_light, next_heavy,
+                        jnp.where(spill, after, s_topic))
+    _, cut, alias = jax.lax.sort((s_topic, s_cut, s_alias), dimension=1,
+                                 num_keys=1)
+    return cut.astype(jnp.float32), alias, u.astype(jnp.float32)
 
 
 def build_alias_int(w: jax.Array
@@ -260,10 +290,7 @@ def build_alias_tables(counts: jax.Array, prior: jax.Array
 
     ``W`` (the integer proposal masses) is returned alongside the table
     because the MH acceptance must evaluate the proposal density from the
-    same quantized grid the table was built on.  Callers building several
-    table families per round (word rows + doc rows) should concatenate
-    their count rows and call ONCE — the K-step pairing loop then runs a
-    single time over all rows instead of once per family.
+    same quantized grid the table was built on.
     """
     prior = jnp.broadcast_to(prior, counts.shape)
     w = int_masses(counts, prior)
@@ -271,33 +298,53 @@ def build_alias_tables(counts: jax.Array, prior: jax.Array
     return cut, alias, u_cap, w
 
 
-def build_alias_int_np(w: np.ndarray
-                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Numpy mirror of :func:`build_alias_int`, op-for-op (f32 single-op
-    chains, LIFO stacks, ascending fill) — tests assert bit-equality."""
+def alias_int_cells_np(w: np.ndarray
+                       ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """The sweep's cells of one row in exact integers: ``w`` [K] ->
+    (cut [K] int64 in ``[0, U]``, alias [K] int32, U).
+
+    Numpy mirror of :func:`build_alias_int_rows`' closed form in int64
+    (64-bit prefix sums, ``searchsorted`` for the merge)."""
     w = np.asarray(w, np.int32)
     k = w.shape[0]
-    u_cap = np.float32(w.sum(dtype=np.int64).astype(np.int32))
-    m = w.astype(np.float32) * np.float32(k)
-    cut = np.full(k, u_cap, np.float32)
+    u = int(w.sum(dtype=np.int64).astype(np.int32))
+    m = w.astype(np.int64) * k
+    light = m < u
+    d = np.where(light, u - m, 0)
+    din = np.cumsum(d)
+    dex = din - d
+    ein = np.cumsum(np.where(light, 0, m - u))
+    lights, heavies = np.flatnonzero(light), np.flatnonzero(~light)
+    cut = np.full(k, u, np.int64)
     alias = np.arange(k, dtype=np.int32)
-    small = [i for i in range(k) if m[i] < u_cap]
-    large = [i for i in range(k) if not (m[i] < u_cap)]
-    while small and large:
-        s = small.pop()
-        lg = large.pop()
-        cut[s] = m[s]
-        alias[s] = lg
-        m[lg] = (m[lg] + m[s]) - u_cap
-        (small if m[lg] < u_cap else large).append(lg)
-    return cut, alias, u_cap
+    cut[lights] = m[lights]
+    first = np.searchsorted(ein[heavies], dex[lights], side="right")
+    alias[lights] = heavies[np.minimum(first, len(heavies) - 1)]
+    inner = heavies[:-1]                   # the last heavy is always full
+    before = np.searchsorted(dex[lights], ein[inner], side="left")
+    overshoot = np.r_[0, din[lights]][before] - ein[inner]
+    spill = overshoot > 0
+    cut[inner[spill]] = u - overshoot[spill]
+    alias[inner[spill]] = heavies[1:][spill]
+    return cut, alias, u
+
+
+def build_alias_int_np(w: np.ndarray
+                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Numpy mirror of :func:`build_alias_int` — tests assert the two
+    agree bit for bit."""
+    cut, alias, u = alias_int_cells_np(w)
+    return (cut.astype(np.int32).astype(np.float32), alias,
+            np.float32(np.int32(u)))
 
 
 def alias_table_masses(cut: np.ndarray, alias: np.ndarray,
                        u_cap: float) -> np.ndarray:
     """Reconstruct the (·K-scaled) masses an integer-grid table encodes:
     topic ``t`` gets ``cut[t]`` from its own cell plus ``U - cut[j]`` from
-    every cell aliased to it.  Equals ``f32(w)·K`` up to fp tolerance."""
+    every cell aliased to it.  Equals ``w·K`` exactly for the integer
+    cells of :func:`alias_int_cells_np`, and for f32 cells while every
+    cut is below 2²⁴."""
     mass = cut.astype(np.float64).copy()
     np.add.at(mass, alias, np.float64(u_cap) - cut.astype(np.float64))
     return mass

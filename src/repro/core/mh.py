@@ -167,7 +167,7 @@ def block_proposal_tables(cdk: jax.Array, ckt_block: jax.Array,
                           alpha: jax.Array, beta) -> Tuple[tuple, tuple]:
     """Round-start proposal state for one block: ONE concatenated table
     build over the word rows (prior β) and doc rows (prior α), so the
-    K-step pairing loop runs once over ``Vb + D_loc`` rows.  Returns
+    row-wise merge runs once over ``Vb + D_loc`` rows.  Returns
     ``(word_table, doc_table)``, each ``(cut, alias, U, W)``.
 
     Shared by ``sweep_block_mh`` and the Pallas wrapper
@@ -192,7 +192,7 @@ def build_word_tables(ckt_block: jax.Array, beta) -> jax.Array:
     packed rotatable layout: [Vb, K] counts -> [3, Vb, K] int32.
 
     Per-row bits are identical to the rows :func:`block_proposal_tables`
-    builds — the Vose pairing is row-independent, so splitting the word
+    builds — the sweep pairing is row-independent, so splitting the word
     rows out of the concatenated build changes nothing — which is what
     lets the per-iteration schedule coexist with the per-round one."""
     vb, k = ckt_block.shape

@@ -9,8 +9,9 @@ import numpy as np
 import pytest
 
 from repro.core.alias import (SCALE, alias_cell_masses, alias_draw_int_np,
-                              alias_draw_np, alias_table_masses,
-                              build_alias_int, build_alias_int_np,
+                              alias_draw_np, alias_int_cells_np,
+                              alias_table_masses, build_alias_int,
+                              build_alias_int_np, build_alias_int_rows,
                               build_alias_np, build_alias_tables,
                               int_masses_np, pack_tables, pack_tables_np,
                               split_cell_uniform, unpack_tables,
@@ -74,9 +75,10 @@ INT_CASES = [
 
 @pytest.mark.parametrize("counts,prior", INT_CASES, ids=range(len(INT_CASES)))
 def test_int_builder_jax_bit_equals_numpy_mirror(counts, prior):
-    """The device builder and its numpy mirror share op order and stack
-    discipline — tables must agree BIT FOR BIT (the draw-for-draw replay
-    of the MH backend rests on exactly this determinism)."""
+    """The device builder and its numpy mirror compute the same closed
+    form in exact integers — tables must agree BIT FOR BIT (the
+    draw-for-draw replay of the MH backend rests on exactly this
+    determinism)."""
     w = int_masses_np(counts, prior)
     cut_np, alias_np, u_np = build_alias_int_np(w)
     cut_j, alias_j, u_j = (np.asarray(x)
@@ -88,16 +90,129 @@ def test_int_builder_jax_bit_equals_numpy_mirror(counts, prior):
 
 @pytest.mark.parametrize("counts,prior", INT_CASES, ids=range(len(INT_CASES)))
 def test_int_builder_reconstructs_masses(counts, prior):
-    """Sum of cell masses equals the quantized input masses (·K units)."""
+    """Sum of cell masses equals the quantized input masses (·K units)
+    exactly: every cut here is an integer below 2²⁴, so f32 holds it."""
     w = int_masses_np(counts, prior)
     cut, alias, u_cap = build_alias_int_np(w)
     k = w.shape[0]
     assert ((alias >= 0) & (alias < k)).all()
     assert (cut >= 0).all() and (cut <= u_cap).all()
     mass = alias_table_masses(cut, alias, u_cap)
-    expect = w.astype(np.float64) * k
-    np.testing.assert_allclose(mass, expect, rtol=1e-6,
-                               atol=1e-6 * max(expect.sum(), 1))
+    np.testing.assert_array_equal(mass, w.astype(np.float64) * k)
+
+
+def _sweep_loop(w):
+    """The textbook sequential sweep (Hübschle-Schneider & Sanders): lights
+    in topic order each take their deficit from the current heavy; a heavy
+    left with at most ``U`` closes (a cell short of ``U`` spills onto the
+    next heavy, which may close in turn), and the last heavy stays open."""
+    w = np.asarray(w, np.int64)
+    k = w.shape[0]
+    u = int(w.sum())
+    m = w * k
+    cut = np.full(k, u, np.int64)
+    alias = np.arange(k)
+    lights = [i for i in range(k) if m[i] < u]
+    heavies = [j for j in range(k) if m[j] >= u]
+    rest = m.copy()
+    h = 0
+
+    def close_spent():
+        nonlocal h
+        while h < len(heavies) - 1 and rest[heavies[h]] <= u:
+            j, nxt = heavies[h], heavies[h + 1]
+            if rest[j] < u:
+                cut[j], alias[j] = rest[j], nxt
+                rest[nxt] -= u - rest[j]
+            h += 1
+
+    close_spent()
+    for i in lights:
+        j = heavies[h]
+        cut[i], alias[i] = m[i], j
+        rest[j] -= u - m[i]
+        close_spent()
+    return cut, alias, u
+
+
+def _edge_rows():
+    k = 12
+    one_heavy = np.ones(k, np.int32)
+    one_heavy[5] = 400                     # all light but one
+    alone = np.zeros(k, np.int32)
+    alone[7] = 9                           # one topic holds everything
+    rng = np.random.default_rng(4)
+    rows = {"all_light_but_one": one_heavy,
+            "all_equal": np.full(k, 5, np.int32),
+            "one_topic_holds_all": alone,
+            "k1": np.array([7], np.int32),
+            "spill_cascade": np.array([1, 30, 1, 13, 13, 1, 1, 60, 1],
+                                      np.int32)}
+    for i in range(6):
+        kk = int(rng.integers(2, 64))
+        rows[f"random{i}"] = int_masses_np(
+            rng.integers(0, 200, kk) * (rng.random(kk) < 0.4),
+            np.full(kk, 0.05, np.float32))
+    return rows
+
+
+EDGE_ROWS = _edge_rows()
+
+
+@pytest.mark.parametrize("name", sorted(EDGE_ROWS))
+def test_int_cells_match_sequential_sweep(name):
+    """The closed form pairs exactly as the sequential sweep does."""
+    w = EDGE_ROWS[name]
+    cut, alias, u = alias_int_cells_np(w)
+    cut_l, alias_l, u_l = _sweep_loop(w)
+    assert u == u_l
+    np.testing.assert_array_equal(cut, cut_l)
+    np.testing.assert_array_equal(alias, alias_l)
+
+
+@pytest.mark.parametrize("name", sorted(EDGE_ROWS))
+def test_int_cells_reconstruct_exact_masses(name):
+    """Integer cells give back ``w·K`` exactly, every cut lies in
+    ``[0, U]``, and the device builder equals the mirror bit for bit."""
+    w = EDGE_ROWS[name]
+    k = w.shape[0]
+    cut, alias, u = alias_int_cells_np(w)
+    assert ((cut >= 0) & (cut <= u)).all()
+    np.testing.assert_array_equal(alias_table_masses(cut, alias, u),
+                                  w.astype(np.float64) * k)
+    cut_j, alias_j, u_j = (np.asarray(x)
+                           for x in build_alias_int(jnp.asarray(w)))
+    cut_np, alias_np, u_np = build_alias_int_np(w)
+    np.testing.assert_array_equal(cut_j.view(np.int32),
+                                  cut_np.view(np.int32))
+    np.testing.assert_array_equal(alias_j, alias_np)
+    assert float(u_j) == float(u_np) == float(u)
+
+
+def test_overflow_row_is_exact_on_device_and_mirror():
+    """A row whose ``K·U`` passes 2³¹ (one word with 1.5M tokens at
+    K = 1,000, the size of this corpus's heaviest word): the two-word
+    prefix sums keep the pairing exact, and device == mirror bitwise."""
+    k = 1000
+    counts = np.zeros(k, np.int32)
+    counts[500] = 1_500_000
+    counts[[3, 600, 999]] = [7, 40_000, 250_000]
+    w = int_masses_np(counts, np.full(k, 0.01, np.float32))
+    rows = np.stack([w, np.roll(w, 317)])
+    cut, alias, u = alias_int_cells_np(w)
+    assert k * u > 2 ** 31
+    np.testing.assert_array_equal(alias_table_masses(cut, alias, u),
+                                  w.astype(np.float64) * k)
+    cut_j, alias_j, u_j = (np.asarray(x) for x in
+                           build_alias_int_rows(jnp.asarray(rows)))
+    for i, row in enumerate(rows):
+        cut_np, alias_np, u_np = build_alias_int_np(row)
+        np.testing.assert_array_equal(cut_j[i].view(np.int32),
+                                      cut_np.view(np.int32))
+        np.testing.assert_array_equal(alias_j[i], alias_np)
+        assert float(u_j[i]) == float(u_np)
+    np.testing.assert_array_equal(
+        build_alias_int_np(w)[0], cut.astype(np.float32))
 
 
 def test_int_builder_draws_follow_quantized_distribution():
@@ -111,6 +226,13 @@ def test_int_builder_draws_follow_quantized_distribution():
     freq = np.bincount(d, minlength=5) / len(u)
     target = w / w.sum()
     assert np.abs(freq - target).max() < 0.01
+
+
+def test_builder_refuses_k_past_two_word_range():
+    """At K ≥ 2¹⁵ a word of the two-word prefix sums could overflow:
+    the builder refuses the shape instead of building wrong tables."""
+    with pytest.raises(ValueError, match="two-word"):
+        build_alias_int_rows(jnp.ones((1, 1 << 15), jnp.int32))
 
 
 def test_build_alias_tables_matches_per_row():
@@ -525,8 +647,9 @@ if HAVE_HYPOTHESIS:
     @given(_int_masses_case())
     @settings(max_examples=60, deadline=None)
     def test_int_builder_property(case):
-        """Device builder == numpy mirror bitwise; reconstruction exact up
-        to fp tolerance; every draw index in range."""
+        """Device builder == numpy mirror bitwise; the integer cells
+        reconstruct the masses exactly and pair as the sequential sweep
+        does; every draw index in range."""
         counts, prior = case
         w = int_masses_np(counts, prior)
         cut_np, alias_np, u_np = build_alias_int_np(w)
@@ -537,10 +660,12 @@ if HAVE_HYPOTHESIS:
         assert float(u_j) == float(u_np)
         k = w.shape[0]
         assert ((alias_np >= 0) & (alias_np < k)).all()
-        mass = alias_table_masses(cut_np, alias_np, u_np)
-        expect = w.astype(np.float64) * k
-        np.testing.assert_allclose(mass, expect, rtol=1e-6,
-                                   atol=1e-6 * max(expect.sum(), 1))
+        cut_i, alias_i, u_i = alias_int_cells_np(w)
+        np.testing.assert_array_equal(alias_table_masses(cut_i, alias_i, u_i),
+                                      w.astype(np.float64) * k)
+        cut_l, alias_l, _ = _sweep_loop(w)
+        np.testing.assert_array_equal(cut_i, cut_l)
+        np.testing.assert_array_equal(alias_i, alias_l)
         rng = np.random.default_rng(0)
         d = alias_draw_int_np(cut_np, alias_np, float(u_np),
                               rng.random(256).astype(np.float32))

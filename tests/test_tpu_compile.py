@@ -6,6 +6,8 @@ through its wrapper with ``interpret=False`` for a described, unattached
 ``v5e:2x2`` topology, in the engine's one-token-per-group layout, at
 K = 1024 (pubmed-k1000) and K = 10240 (the K = 10^4 configs padded to the
 lane boundary).  Nothing runs; a compile that passes is not a chip run.
+The alias-table builder, plain XLA and no kernel, is compiled beside
+them: its row sorts along K are what the MH tables rest on.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker
@@ -19,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.alias import build_alias_int_rows
 from repro.core.mh import DEFAULT_MH_CYCLES
 from repro.kernels import ops
 from repro.kernels.sparse_gibbs import sparse_lane_call
@@ -97,3 +100,10 @@ def test_sparse_lane_compiles(one_chip):
              (lane_ops(wcap), lane_ops(dcap), s((t,), jnp.bool_),
               s((t,), i32), s((t,), jnp.bool_), s((t,)), s((t,)),
               s(()), s(())))
+
+
+@pytest.mark.parametrize("k", KS)
+def test_alias_table_build_compiles(one_chip, k):
+    text = build_alias_int_rows.lower(
+        _spec(one_chip)((ROWS, k), jnp.int32)).compile().as_text()
+    assert " sort(" in text and " scatter(" not in text
