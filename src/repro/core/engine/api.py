@@ -61,13 +61,13 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 
 from repro import tracing
 from repro.core.counts import CountState
 from repro.core.engine import state as engine_state
 from repro.core.engine.backends import (iteration_vmap,
-                                        make_shard_map_iteration)
+                                        make_shard_map_iteration, row_spec)
 from repro.core.engine.rounds import resolve_sampler, table_capable
 from repro.core.likelihood import doc_log_likelihood, word_log_likelihood
 from repro.data.corpus import Corpus
@@ -146,60 +146,68 @@ class ModelParallelLDA:
         self.axis = axis
         self.data_axis = data_axis
         self._rng = np.random.default_rng(seed)
-        self._build()
         if backend == "shard_map":
             # 2D (data, model) layout when D > 1 or the caller hands us a
             # mesh that already carries the data axis (lets tests exercise
             # the 2D code path at D = 1).
             use_2d = (self.data_parallel > 1
                       or (mesh is not None and data_axis in mesh.axis_names))
-            need = self.num_shards
-            if mesh is None:
-                if len(jax.devices()) < need:
-                    raise ValueError(
-                        f"shard_map backend needs {need} devices, "
-                        f"have {len(jax.devices())}")
-                if use_2d:
-                    mesh = Mesh(
-                        np.array(jax.devices()[:need]).reshape(
-                            self.data_parallel, self.num_workers),
-                        (data_axis, axis))
-                else:
-                    mesh = Mesh(np.array(jax.devices()[:need]), (axis,))
-            else:
-                # a mismatched mesh would silently drop grid rows (each
-                # device keeps only its first local row) — reject early
-                want = {axis: self.num_workers}
-                if use_2d:
-                    want[data_axis] = self.data_parallel
-                got = dict(mesh.shape)
-                if got != want:
-                    raise ValueError(
-                        f"mesh axes {got} do not match the "
-                        f"(data_parallel={self.data_parallel}, "
-                        f"num_workers={self.num_workers}) grid; expected "
-                        f"exactly {want}")
-            self.mesh = mesh
+            self.mesh = self._grid_mesh(mesh, use_2d)
+            grid_axis = data_axis if use_2d else None
+            self._rows = NamedSharding(self.mesh, row_spec(axis, grid_axis))
             self._iter_fn = make_shard_map_iteration(
-                mesh, axis, sampler_mode, sync_ck,
-                data_axis=data_axis if use_2d else None,
+                self.mesh, axis, sampler_mode, sync_ck,
+                data_axis=grid_axis,
                 table_lifetime=self.table_lifetime,
                 track_error=self.track_error,
                 sampler_args=self.sampler_args)
         else:
             self.mesh = None
+            self._rows = None
             self._iter_fn = None
+        self._build()
+
+    def _grid_mesh(self, mesh: Optional[Mesh], use_2d: bool) -> Mesh:
+        """The caller's mesh, checked against the grid, or one made from
+        the first ``D·M`` devices."""
+        axis, data_axis = self.axis, self.data_axis
+        need = self.data_parallel * self.num_workers
+        if mesh is None:
+            if len(jax.devices()) < need:
+                raise ValueError(
+                    f"shard_map backend needs {need} devices, "
+                    f"have {len(jax.devices())}")
+            if use_2d:
+                return Mesh(np.array(jax.devices()[:need]).reshape(
+                    self.data_parallel, self.num_workers),
+                    (data_axis, axis))
+            return Mesh(np.array(jax.devices()[:need]), (axis,))
+        # a mismatched mesh would silently drop grid rows (each device
+        # keeps only its first local row) — reject early
+        want = {axis: self.num_workers}
+        if use_2d:
+            want[data_axis] = self.data_parallel
+        got = dict(mesh.shape)
+        if got != want:
+            raise ValueError(
+                f"mesh axes {got} do not match the "
+                f"(data_parallel={self.data_parallel}, "
+                f"num_workers={self.num_workers}) grid; expected "
+                f"exactly {want}")
+        return mesh
 
     # -- construction ------------------------------------------------------
     def _build(self) -> None:
-        self.layout = engine_state.build_layout(
+        layout = engine_state.build_layout(
             self.corpus, self.num_workers, self.blocks_per_worker,
             self.data_parallel)
         z0 = self._rng.integers(
             0, self.num_topics, size=self.corpus.num_tokens).astype(np.int32)
         self.z_init = z0
-        self.state = engine_state.init_state(self.layout, self.num_topics,
-                                             z0)
+        state = engine_state.init_state(layout, self.num_topics, z0)
+        with tracing.span(tracing.TRAIN_PLACE):
+            self.layout = engine_state.place_layout(layout, self._rows)
+            self.state = engine_state.place_state(state, self._rows)
         self.iteration_count = 0
 
     # -- layout views (kept as attributes of the facade) -------------------
@@ -309,12 +317,33 @@ class ModelParallelLDA:
                 "at rest (use the streaming engine + sparse family for "
                 "a compressed resident block)")
 
+    def counters(self) -> dict:
+        """What one iteration does, by count (``repro.tracing.COUNTERS``):
+        the token slots it samples, padding included; the corpus's real
+        tokens; and the bytes one worker hands to its ring neighbour over
+        the iteration's rounds: the resident block, its id and, under
+        the iteration table lifetime, its packed ``[3, Vb, K]`` word
+        table."""
+        vb, k = self.resident_block_rows, self.num_topics
+        per_round = 4 * vb * k + 4
+        if self.table_lifetime == "iteration":
+            per_round += 3 * 4 * vb * k
+        return {tracing.SLOTS: self.layout.num_slots,
+                tracing.REAL_TOKENS: self.corpus.num_tokens,
+                tracing.ROTATE_BYTES: self.num_rounds * per_round}
+
     # -- stepping ----------------------------------------------------------
     def _uniforms(self) -> jax.Array:
+        """The iteration's uniforms, one per (round, grid row, token
+        slot), drawn ``[rounds, rows, T]`` from the chain's rng.  The
+        vmap iteration takes them so; on a mesh they go ``[rows, rounds,
+        T]``, each row straight to its device."""
         b, r, cap = self.num_rounds, self.num_shards, self.capacity
         with tracing.span(tracing.TRAIN_UNIFORMS):
-            # [rounds, rows, T]
-            return jnp.asarray(self._rng.random((b, r, cap), np.float32))
+            u = self._rng.random((b, r, cap), np.float32)
+            if self._rows is None:
+                return jnp.asarray(u)
+            return jax.device_put(u.swapaxes(0, 1), self._rows)
 
     def step(self) -> None:
         """Run one iteration (= S·M rounds, every token sampled once)."""
@@ -337,7 +366,7 @@ class ModelParallelLDA:
                 s = self.state
                 out = self._iter_fn(
                     s.cdk, s.ckt, s.block_id, s.ck_synced, s.ck_local, s.z,
-                    jnp.swapaxes(u, 0, 1), self.doc, self.woff, self.mask,
+                    u, self.doc, self.woff, self.mask,
                     self.alpha, jnp.float32(self.beta),
                     jnp.float32(self.vbeta))
                 self.state = engine_state.MPState(*out[:6])
@@ -525,13 +554,9 @@ class ModelParallelLDA:
                       tuple(p) for p in cfg["sampler_args"]),
                   store=(store if store is not None
                          else cfg.get("store", "dense")))
-        lda.state = engine_state.MPState(
-            cdk=jnp.asarray(arrays["cdk"]),
-            ckt=jnp.asarray(arrays["ckt"]),
-            block_id=jnp.asarray(arrays["block_id"]),
-            ck_synced=jnp.asarray(arrays["ck_synced"]),
-            ck_local=jnp.asarray(arrays["ck_local"]),
-            z=jnp.asarray(arrays["z"]))
+        with tracing.span(tracing.TRAIN_PLACE):
+            lda.state = engine_state.place_state(
+                engine_state.MPState(**arrays), lda._rows)
         lda._rng.bit_generator.state = rng_state
         lda.iteration_count = int(cfg["iteration_count"])
         return lda

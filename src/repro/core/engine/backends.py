@@ -230,6 +230,13 @@ def iteration_vmap(state: MPState, u, doc, woff, mask, alpha, beta, vbeta,
     return MPState(*carry), errs
 
 
+def row_spec(axis: str, data_axis: str | None = None) -> P:
+    """The partition of every per-row array of the shard_map iteration:
+    its leading ``R = D·M`` grid axis over the model ``axis``, or over
+    ``(data_axis, axis)`` data-major on the 2D grid."""
+    return P((data_axis, axis)) if data_axis is not None else P(axis)
+
+
 def make_shard_map_iteration(mesh: Mesh, axis: str, sampler_mode: str,
                              sync_ck: bool, data_axis: str | None = None,
                              table_lifetime: str = "round",
@@ -342,7 +349,7 @@ def make_shard_map_iteration(mesh: Mesh, axis: str, sampler_mode: str,
         return (cdk[None], ckt[None], blk[None], ck_syn, ck_loc[None],
                 z[None], errs)
 
-    w = P(ck_axes) if data_axis is not None else P(axis)
+    w = row_spec(axis, data_axis)
     return jax.jit(jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(w, w, w, P(), w, w, w, w, w, w, P(), P(), P()),

@@ -32,6 +32,7 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import schedule as sched
 from repro.core.counts import CountState
@@ -100,7 +101,9 @@ class EngineLayout:
     """Static (non-pytree) engine geometry: shards, indexes, partition.
 
     Built once per ``(corpus, M, S)``; everything here is host-side numpy
-    plus the device-resident token-layout arrays shared by every round.
+    plus the token-layout arrays shared by every round, which
+    :func:`build_layout` leaves in host memory and :func:`place_layout`
+    puts on the device(s).
     """
 
     corpus: Corpus
@@ -114,6 +117,12 @@ class EngineLayout:
     doc: jax.Array    # [R, B, T] int32
     woff: jax.Array   # [R, B, T] int32
     mask: jax.Array   # [R, B, T] bool
+
+    @property
+    def num_slots(self) -> int:
+        """Token slots one iteration samples, padding included:
+        ``B·R·T`` (every round, every grid row, its whole group)."""
+        return self.num_blocks * self.num_shards * self.capacity
 
     @property
     def num_blocks(self) -> int:
@@ -140,7 +149,8 @@ def build_layout(corpus: Corpus, num_workers: int,
                  data_parallel: int = 1) -> EngineLayout:
     """Shard documents ``R = D·M`` ways, partition the vocabulary into
     ``B = S·M`` blocks (shared across data replicas), and build each grid
-    cell's per-block inverted index with a common capacity."""
+    cell's per-block inverted index with a common capacity.  The token
+    arrays stay in host memory until :func:`place_layout`."""
     num_blocks = num_workers * blocks_per_worker
     partition = sched.partition_vocab(corpus.vocab_size, num_blocks)
     sched.validate_schedule_2d(data_parallel, num_workers, blocks_per_worker)
@@ -157,8 +167,7 @@ def build_layout(corpus: Corpus, num_workers: int,
         blocks_per_worker=blocks_per_worker, data_parallel=data_parallel,
         partition=partition,
         shards=shards, indexes=indexes, capacity=cap,
-        doc=jnp.asarray(doc), woff=jnp.asarray(woff),
-        mask=jnp.asarray(mask))
+        doc=doc, woff=woff, mask=mask)
 
 
 def init_state(layout: EngineLayout, num_topics: int,
@@ -170,7 +179,8 @@ def init_state(layout: EngineLayout, num_topics: int,
     opens holding block ``m`` exactly as the original engine did.  With
     ``D > 1`` data replicas the block queues of the ``M`` model positions
     are tiled along data: grid row ``d·M + m`` opens with the same queue
-    as row ``m`` (replicated model, DESIGN.md §8).
+    as row ``m`` (replicated model, DESIGN.md §8).  The arrays stay in
+    host memory until :func:`place_state`.
     """
     m, s_ = layout.num_workers, layout.blocks_per_worker
     d_, r_ = layout.data_parallel, layout.num_shards
@@ -202,13 +212,51 @@ def init_state(layout: EngineLayout, num_topics: int,
     block_id = np.broadcast_to(block_id[None], (d_, m, s_)) \
         .reshape(r_, s_)
     return MPState(
-        cdk=jnp.asarray(cdk),
-        ckt=jnp.asarray(np.ascontiguousarray(slots)),
-        block_id=jnp.asarray(np.ascontiguousarray(block_id)),
-        ck_synced=jnp.asarray(ck),
-        ck_local=jnp.broadcast_to(jnp.asarray(ck), (r_, k)),
-        z=jnp.asarray(zarr),
+        cdk=cdk,
+        ckt=np.ascontiguousarray(slots),
+        block_id=np.ascontiguousarray(block_id),
+        ck_synced=ck,
+        ck_local=np.broadcast_to(ck, (r_, k)),
+        z=zarr,
     )
+
+
+# ---------------------------------------------------------------------------
+# Placement
+# ---------------------------------------------------------------------------
+# Every per-row array has the grid rows ``R = D·M`` as its leading axis.
+# On one device (the vmap backend) the arrays are plain device arrays.  On
+# a mesh (shard_map) each is split by ``rows``, the partition the
+# iteration's ``in_specs`` give it, so every device receives only its own
+# rows, straight from host memory; ``ck_synced`` is whole on each.
+
+def put(x, rows: NamedSharding | None = None) -> jax.Array:
+    """``x`` on the default device (``rows=None``) or split over the mesh
+    by ``rows``."""
+    return jnp.asarray(x) if rows is None else jax.device_put(x, rows)
+
+
+def _whole(rows: NamedSharding | None) -> NamedSharding | None:
+    return None if rows is None else NamedSharding(rows.mesh, P())
+
+
+def place_layout(layout: EngineLayout,
+                 rows: NamedSharding | None = None) -> EngineLayout:
+    """The layout with its token arrays placed (see :func:`put`)."""
+    return dataclasses.replace(layout, doc=put(layout.doc, rows),
+                               woff=put(layout.woff, rows),
+                               mask=put(layout.mask, rows))
+
+
+def place_state(state: MPState,
+                rows: NamedSharding | None = None) -> MPState:
+    """The state placed (see :func:`put`): every per-row array split by
+    ``rows``, the agreed totals ``ck_synced`` whole on every device."""
+    return MPState(cdk=put(state.cdk, rows), ckt=put(state.ckt, rows),
+                   block_id=put(state.block_id, rows),
+                   ck_synced=put(state.ck_synced, _whole(rows)),
+                   ck_local=put(state.ck_local, rows),
+                   z=put(state.z, rows))
 
 
 def gather_counts(layout: EngineLayout, state: MPState,

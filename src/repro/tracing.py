@@ -7,6 +7,10 @@ recorded.  Device scopes are ``jax.named_scope`` names: they enter the
 JAX name stack of every op traced under them, which the profiler shows
 as each device op's ``tf_op``, and cost nothing on the device.
 
+Counters are plain numbers the program reports by name; training's
+(``ModelParallelLDA.counters``) say what one iteration does: slots
+sampled, real tokens, bytes handed on through the ring.
+
 Readers match on exact names, so a span's arguments never enter its
 name: :func:`span` passes them as the event's stats, and only while a
 trace is being recorded.
@@ -37,11 +41,19 @@ FOLDIN_FETCH = "foldin.fetch"     # the host blocked on the device's result
 FOLDIN_THETA = "foldin.theta"     # mixtures from the fetched counts
 
 # -- host spans of training (``core/engine/api.py``)
+TRAIN_PLACE = "train.place"         # layout and state put on the device(s)
 TRAIN_UNIFORMS = "train.uniforms"   # the iteration's uniforms, drawn and put
 TRAIN_DISPATCH = "train.dispatch"   # the call into the jitted iteration
 
 SPANS = (SERVE_BATCH, SERVE_DRAWS, FOLDIN_PACK, FOLDIN_UPLOAD, FOLDIN_RUN,
-         FOLDIN_FETCH, FOLDIN_THETA, TRAIN_UNIFORMS, TRAIN_DISPATCH)
+         FOLDIN_FETCH, FOLDIN_THETA, TRAIN_PLACE, TRAIN_UNIFORMS,
+         TRAIN_DISPATCH)
+
+# -- per-iteration counters of training (``ModelParallelLDA.counters``)
+SLOTS = "slots"                   # token slots sampled, padding included
+REAL_TOKENS = "real_tokens"       # the corpus's tokens
+ROTATE_BYTES = "rotate_bytes"     # bytes one worker hands on in the ring
+COUNTERS = (SLOTS, REAL_TOKENS, ROTATE_BYTES)
 
 
 def span(name: str, **args) -> TraceAnnotation:

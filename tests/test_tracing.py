@@ -44,9 +44,8 @@ def _lower_step(lda: ModelParallelLDA):
             sampler_args=lda.sampler_args)
     s = lda.state
     return lda._iter_fn.lower(
-        s.cdk, s.ckt, s.block_id, s.ck_synced, s.ck_local, s.z,
-        jnp.swapaxes(u, 0, 1), lda.doc, lda.woff, lda.mask, lda.alpha,
-        beta, vbeta)
+        s.cdk, s.ckt, s.block_id, s.ck_synced, s.ck_local, s.z, u,
+        lda.doc, lda.woff, lda.mask, lda.alpha, beta, vbeta)
 
 
 @pytest.mark.parametrize("lifetime", ["round", "iteration"])
@@ -235,3 +234,34 @@ def test_one_tick_traces_nested_spans_under_bare_names(snap, tmp_path):
     # arguments are stats, never part of a name
     assert not any("#" in n or "=" in n for n, *_ in events
                    if n.split(".")[0] in ("serve", "foldin"))
+
+
+# ---------------------------------------------------------------------------
+# Training's set-up span and per-iteration counters
+# ---------------------------------------------------------------------------
+
+def test_training_names_are_pinned():
+    """The benchmark's readers match these strings letter for letter."""
+    assert (tracing.TRAIN_PLACE, tracing.TRAIN_UNIFORMS,
+            tracing.TRAIN_DISPATCH) == ("train.place", "train.uniforms",
+                                        "train.dispatch")
+    assert tracing.COUNTERS == ("slots", "real_tokens", "rotate_bytes")
+    assert (tracing.SAMPLE, tracing.ROTATE, tracing.CK_SYNC) == (
+        "lda.sample", "lda.rotate", "lda.ck_sync")
+
+
+@pytest.mark.parametrize("backend", ["vmap", "shard_map"])
+def test_build_traces_its_placement(tiny_corpus, tmp_path, backend):
+    from jax.profiler import ProfileData
+    corpus, _, _ = tiny_corpus
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        ModelParallelLDA(corpus, K, num_workers=4, backend=backend)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = [e.name for p in ProfileData.from_file(path).planes
+             if p.name == "/host:CPU" for ln in p.lines for e in ln.events]
+    assert names.count(tracing.TRAIN_PLACE) == 1
