@@ -17,6 +17,10 @@ frozen, which changes the systems story completely (DESIGN.md §11):
   is static, so the per-word tables (`core/alias.py`, packed layout) are
   built once per :class:`ModelSnapshot` and amortize over EVERY query
   token served from it, not just one round's.
+* **the model stays on the device** — the snapshot arrays the sweeps
+  read are placed on the device once per snapshot
+  (:meth:`ModelSnapshot.device_operands`); a call converts only its
+  query's arrays.
 * **replayable** — uniforms and initial assignments are drawn externally
   (same convention as the trainer), so a batched device fold-in is
   replayed draw-for-draw by the serial host oracle
@@ -50,6 +54,30 @@ from repro.core.sampler import sample_from_mass
 # burn-in is short because only D_loc = 1 rows of state mix.
 DEFAULT_FOLD_IN_SWEEPS = 5
 
+# the snapshot arrays each fold-in family's sweeps take, in argument order
+RESIDENT_OPERANDS = {
+    "scan": ("word_term", "alpha"),
+    "sparse": ("word_term", "xcs", "sx"),
+    "mh": ("ckt", "ck", "word_tables", "alpha", "beta", "vbeta"),
+}
+
+
+def fold_in_family(sampler: str) -> str:
+    """The fold-in sweeps ``sampler`` runs: ``"scan"``, ``"sparse"``
+    (both sparse names) or ``"mh"`` (any table-capable registry
+    sampler)."""
+    if sampler == "scan":
+        return "scan"
+    if sampler in ("sparse", "sparse_pallas"):
+        return "sparse"
+    from repro.core.engine.rounds import table_capable
+    if table_capable(sampler):
+        return "mh"
+    raise ValueError(
+        f"unknown fold-in sampler {sampler!r}; expected 'scan', "
+        "'sparse'/'sparse_pallas', or a table-capable registry "
+        "sampler (the MH family)")
+
 
 # ---------------------------------------------------------------------------
 # Frozen model snapshot (the serving export)
@@ -67,6 +95,12 @@ class ModelSnapshot:
     and exactly once: the ``scan`` sampler and perplexity scoring never
     need it, and rebuilding from counts is bit-deterministic, which is
     why :meth:`save` persists only the counts.
+
+    The arrays fold-in reads are also kept on the device
+    (:meth:`device_operands`): placed once, at the first serving use,
+    and shared by every call until :meth:`release_device`.  The host
+    views stay; the oracles and :meth:`save` read those, and the device
+    copies hold the same bytes.
     """
 
     ckt: np.ndarray                       # [V, K] int32 word-topic counts
@@ -84,6 +118,14 @@ class ModelSnapshot:
         dataclasses.field(default=None, repr=False, compare=False)
     _fingerprint: Optional[str] = \
         dataclasses.field(default=None, repr=False, compare=False)
+    # device copies by RESIDENT_OPERANDS name, and how often and how many
+    # host bytes were placed into it
+    _device: dict = dataclasses.field(default_factory=dict, init=False,
+                                      repr=False, compare=False)
+    placements: int = dataclasses.field(default=0, init=False, repr=False,
+                                        compare=False)
+    placed_bytes: int = dataclasses.field(default=0, init=False,
+                                          repr=False, compare=False)
 
     @classmethod
     def from_counts(cls, ckt, ck=None, alpha=0.1, beta=0.01,
@@ -171,12 +213,60 @@ class ModelSnapshot:
         return self._fingerprint
 
     def ensure_tables(self) -> np.ndarray:
-        """Build (once) and return the packed per-word alias tables."""
+        """Build (once) and return the packed per-word alias tables,
+        fetched from the device copy where one is placed."""
         if self.word_tables is None:
-            from repro.core.mh import build_word_tables
-            self.word_tables = np.asarray(build_word_tables(
-                jnp.asarray(self.ckt), jnp.float32(self.beta)))
+            tables = self._device.get("word_tables")
+            if tables is None:
+                from repro.core.mh import build_word_tables
+                tables = build_word_tables(jnp.asarray(self.ckt),
+                                           jnp.float32(self.beta))
+            self.word_tables = np.asarray(tables)
         return self.word_tables
+
+    # -- device residency --------------------------------------------------
+    def _host_operand(self, name: str):
+        if name in ("xcs", "sx"):
+            xcs, sx = self.sparse_state()
+            return sx if name == "sx" else xcs
+        if name == "word_term":
+            return self.word_term()
+        if name in ("beta", "vbeta"):
+            return np.float32(getattr(self, name))
+        return getattr(self, name)
+
+    def device_operands(self, sampler: str) -> tuple:
+        """The device arrays ``sampler``'s fold-in sweeps take from the
+        snapshot (``RESIDENT_OPERANDS``), placed on the first call that
+        needs them and reused by every later one.
+
+        A placement converts the host views under the ``foldin.place``
+        span and adds one to ``placements`` and its host bytes to
+        ``placed_bytes``; a host view that is already a device array is
+        taken as it is and counts 0.  MH word tables not yet built are
+        built on the device from the placed counts and kept there."""
+        names = RESIDENT_OPERANDS[fold_in_family(sampler)]
+        todo = [n for n in names if n not in self._device]
+        if todo:
+            host = {n: self._host_operand(n) for n in todo
+                    if n != "word_tables" or self.word_tables is not None}
+            nbytes = sum(np.asarray(x).nbytes for x in host.values()
+                         if not isinstance(x, jax.Array))
+            with tracing.span(tracing.FOLDIN_PLACE, bytes=nbytes):
+                for n in todo:
+                    if n in host:
+                        self._device[n] = jnp.asarray(host[n])
+                    else:
+                        from repro.core.mh import build_word_tables
+                        self._device[n] = build_word_tables(
+                            self._device["ckt"], jnp.float32(self.beta))
+            self.placements += 1
+            self.placed_bytes += int(nbytes)
+        return tuple(self._device[n] for n in names)
+
+    def release_device(self) -> None:
+        """Drop the device copies; a later fold-in places them again."""
+        self._device.clear()
 
     # -- persistence -------------------------------------------------------
     def save(self, path: str) -> None:
@@ -488,16 +578,19 @@ def fold_in(snapshot: ModelSnapshot, word: np.ndarray, mask: np.ndarray,
     registry sampler (``"mh"``/``"mh_pallas"`` — the MH pair draws
     identically, as in training).
 
-    Every operand of the jitted sweeps is converted to a device array in
-    one place; ``h2d_bytes`` counts the host arrays among them (snapshot
-    arrays included, on every call), and arrays already on the device
-    count 0.
+    The snapshot's operands come from its device copies
+    (:meth:`ModelSnapshot.device_operands`), placed by the first call
+    that needs them; the query's are converted here.  ``h2d_bytes``
+    counts the host bytes the call converted: the query's, plus the
+    snapshot's when this call placed them.  Arrays already on the
+    device count 0.
     """
     word = np.asarray(word, np.int32)
     mask = np.asarray(mask, bool)
     if word.shape != mask.shape or word.ndim != 2:
         raise ValueError(f"word/mask must share a [Q, T] shape, got "
                          f"{word.shape} vs {mask.shape}")
+    family = fold_in_family(sampler)
     k = snapshot.num_topics
     if rng is None:
         rng = np.random.default_rng(seed)
@@ -508,41 +601,37 @@ def fold_in(snapshot: ModelSnapshot, word: np.ndarray, mask: np.ndarray,
     u = np.asarray(u, np.float32)
     cdk0 = init_query_cdk(z0, mask, k)
 
-    if sampler == "scan":
-        sweeps = _fold_in_scan_sweeps
-        operands = (cdk0, snapshot.word_term(), word, z0, mask, u,
-                    snapshot.alpha)
-    elif sampler in ("sparse", "sparse_pallas"):
-        # one jnp implementation serves both names: with the model frozen
-        # there is no per-round lane extraction to fuse, so the serving
-        # path has no separate kernel form (the alias keeps `--sampler`
-        # choices symmetric between training and inference).
-        xcs, sx = snapshot.sparse_state()
-        sweeps = partial(_fold_in_sparse_sweeps, dcap=min(k, word.shape[1]))
-        operands = (cdk0, snapshot.word_term(), xcs, sx, word, z0, mask, u)
-    else:
-        from repro.core.engine.rounds import table_capable
-        if not table_capable(sampler):
-            raise ValueError(
-                f"unknown fold-in sampler {sampler!r}; expected 'scan', "
-                "'sparse'/'sparse_pallas', or a table-capable registry "
-                "sampler (the MH family)")
-        sweeps = partial(_fold_in_mh_sweeps, sampler_mode=sampler,
-                         num_cycles=num_cycles)
-        operands = (cdk0, snapshot.ckt, snapshot.ck,
-                    snapshot.ensure_tables(), word, z0, mask, u,
-                    snapshot.alpha, np.float32(snapshot.beta),
-                    np.float32(snapshot.vbeta))
-
-    h2d = sum(np.asarray(x).nbytes for x in operands
-              if not isinstance(x, jax.Array))
-    with tracing.span(tracing.FOLDIN_UPLOAD, bytes=h2d):
-        operands = [jnp.asarray(x) for x in operands]
+    placed = snapshot.placed_bytes
+    model = snapshot.device_operands(sampler)
+    query = (cdk0, word, z0, mask, u)
+    q_bytes = sum(np.asarray(x).nbytes for x in query
+                  if not isinstance(x, jax.Array))
+    with tracing.span(tracing.FOLDIN_UPLOAD, bytes=q_bytes):
+        cdk0, word_d, z0, mask_d, u = [jnp.asarray(x) for x in query]
     with tracing.span(tracing.FOLDIN_RUN):
-        cdk, z = sweeps(*operands)
+        if family == "scan":
+            wterm, alpha = model
+            cdk, z = _fold_in_scan_sweeps(cdk0, wterm, word_d, z0, mask_d,
+                                          u, alpha)
+        elif family == "sparse":
+            # one jnp implementation serves both names: with the model
+            # frozen there is no per-round lane extraction to fuse, so
+            # the serving path has no separate kernel form (the alias
+            # keeps `--sampler` choices symmetric between training and
+            # inference).
+            cdk, z = _fold_in_sparse_sweeps(cdk0, *model, word_d, z0,
+                                            mask_d, u,
+                                            dcap=min(k, word.shape[1]))
+        else:
+            ckt, ck, wtab, alpha, beta, vbeta = model
+            cdk, z = _fold_in_mh_sweeps(cdk0, ckt, ck, wtab, word_d, z0,
+                                        mask_d, u, alpha, beta, vbeta,
+                                        sampler_mode=sampler,
+                                        num_cycles=num_cycles)
     with tracing.span(tracing.FOLDIN_FETCH):
         cdk = np.asarray(cdk)
         z = np.asarray(z)
     with tracing.span(tracing.FOLDIN_THETA):
         theta = theta_from_cdk(cdk, snapshot.alpha)
+    h2d = snapshot.placed_bytes - placed + q_bytes
     return FoldInResult(cdk=cdk, z=z, theta=theta, h2d_bytes=int(h2d))

@@ -271,7 +271,7 @@ def main() -> None:
         h2d_mb = sum(b["h2d_bytes"] for b in log) / len(log) / 1e6
         wait_ms = (1e3 * sum(b["wait_s"] for b in log)
                    / sum(b["size"] for b in log))
-        print(f"host path: {h2d_mb:,.1f} MB host->device per batch, "
+        print(f"host path: {h2d_mb:,.4f} MB host->device per batch, "
               f"queue wait {wait_ms:.2f} ms per request "
               f"(last {len(log)} batches)")
     st = sched.stats()

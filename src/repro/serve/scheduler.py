@@ -162,7 +162,8 @@ def reference_theta(snapshot: ModelSnapshot, tokens: Sequence[int], *,
     (snapshot contents, token multiset, seed contract) that every
     scheduler response must equal bitwise — batched or alone, cached or
     fresh, before or after any number of swaps, on any replica.  The
-    hot-swap and cache equivalence tests anchor on this."""
+    hot-swap and cache equivalence tests anchor on this.  An installed
+    snapshot is already on the device, so this places nothing new."""
     canon = canonical_tokens(tokens)
     z0, u = request_draws(seed, snapshot.fingerprint(),
                           multiset_digest(canon), canon.size,
@@ -400,8 +401,9 @@ class ServingScheduler:
     # -- model installation / hot-swap ------------------------------------
     def _install(self, snapshot: ModelSnapshot) -> None:
         # replicas share ONE snapshot object, so per-snapshot derived
-        # state (alias tables, sparse cumsums) is built once and pointed
-        # to N times — a replica is pure compute, not memory
+        # state (alias tables, sparse cumsums) and its device copy are
+        # built and placed once and pointed to N times — a replica is
+        # pure compute, not memory
         self._snapshots[self.epoch] = snapshot
         self._fp[self.epoch] = snapshot.fingerprint()
         self._servers[self.epoch] = [
@@ -440,8 +442,10 @@ class ServingScheduler:
         A pointer flip: the new epoch's replicas are created, new
         admissions bind them immediately, and requests already admitted
         (queued or in flight) complete against the snapshot stamped on
-        them at admission — the old epoch's servers are released only
-        once its last queued request drains.  The cache is cleared: its
+        them at admission — the old epoch's servers, and its snapshot's
+        device copy, are released only once its last queued request
+        drains.  The new snapshot is placed on the device here, so until
+        then both are resident.  The cache is cleared: its
         entries answer for the previous fingerprint.  Returns the new
         epoch.
 
@@ -465,10 +469,14 @@ class ServingScheduler:
         return self.epoch
 
     def _release_drained_epochs(self) -> None:
+        # a drained epoch's device copy is freed here, unless a live
+        # epoch installed the same snapshot object
         live = {p.epoch for p in self._queue} | {self.epoch}
         for e in [e for e in self._servers if e not in live]:
             del self._servers[e]
-            del self._snapshots[e]
+            snap = self._snapshots.pop(e)
+            if not any(s is snap for s in self._snapshots.values()):
+                snap.release_device()
 
     @property
     def snapshot(self) -> ModelSnapshot:

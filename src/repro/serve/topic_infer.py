@@ -45,8 +45,11 @@ class TopicInferenceServer:
     (DESIGN.md §12) — per-snapshot derived state (packed word alias
     tables for MH, the dense-segment cumsum for sparse) is built once at
     server construction and shared by every query (the LightLDA
-    frozen-model ideal).  Randomness flows from one seeded generator, so
-    a server's response stream is reproducible end to end.
+    frozen-model ideal), and placed on the device there with the rest of
+    the snapshot arrays the sampler reads: a batch then sends only its
+    queries to the device.  Servers sharing a snapshot share its one
+    device copy.  Randomness flows from one seeded generator, so a
+    server's response stream is reproducible end to end.
     """
 
     def __init__(self, snapshot: ModelSnapshot, sampler: str = "mh",
@@ -58,15 +61,12 @@ class TopicInferenceServer:
         self.min_batch_bucket = int(min_batch_bucket)
         self.min_token_bucket = int(min_token_bucket)
         self._rng = np.random.default_rng(seed)
-        if sampler in ("sparse", "sparse_pallas"):
-            snapshot.sparse_state()       # build once, serve many
-        elif sampler != "scan":
-            snapshot.ensure_tables()      # build once, serve many
+        snapshot.device_operands(sampler)     # build once, serve many
         # serving observability: how many calls landed in each bucket
         # (tests assert reuse; ops would watch for bucket explosion)
         self.bucket_calls: Dict[Tuple[int, int], int] = {}
         self.docs_served = 0
-        self.h2d_bytes = 0                # host -> device bytes, all calls
+        self.h2d_bytes = 0                # host -> device bytes of its calls
 
     def bucket_shape(self, docs: Sequence[Sequence[int]]
                      ) -> Tuple[int, int]:

@@ -35,7 +35,8 @@ SCOPES = (DOC_TABLES, WORD_TABLES, SAMPLE, RECONCILE, ROTATE, CK_SYNC,
 SERVE_BATCH = "serve.batch"       # one dispatched batch
 SERVE_DRAWS = "serve.draws"       # the batch's seed-contract draws
 FOLDIN_PACK = "foldin.pack"       # bucket packing of the queries and draws
-FOLDIN_UPLOAD = "foldin.upload"   # host -> device conversions
+FOLDIN_PLACE = "foldin.place"     # a snapshot's operands put on the device
+FOLDIN_UPLOAD = "foldin.upload"   # host -> device conversions of a query
 FOLDIN_RUN = "foldin.run"         # the call of the jitted sweeps
 FOLDIN_FETCH = "foldin.fetch"     # the host blocked on the device's result
 FOLDIN_THETA = "foldin.theta"     # mixtures from the fetched counts
@@ -45,8 +46,8 @@ TRAIN_PLACE = "train.place"         # layout and state put on the device(s)
 TRAIN_UNIFORMS = "train.uniforms"   # the iteration's uniforms, drawn and put
 TRAIN_DISPATCH = "train.dispatch"   # the call into the jitted iteration
 
-SPANS = (SERVE_BATCH, SERVE_DRAWS, FOLDIN_PACK, FOLDIN_UPLOAD, FOLDIN_RUN,
-         FOLDIN_FETCH, FOLDIN_THETA, TRAIN_PLACE, TRAIN_UNIFORMS,
+SPANS = (SERVE_BATCH, SERVE_DRAWS, FOLDIN_PACK, FOLDIN_PLACE, FOLDIN_UPLOAD,
+         FOLDIN_RUN, FOLDIN_FETCH, FOLDIN_THETA, TRAIN_PLACE, TRAIN_UNIFORMS,
          TRAIN_DISPATCH)
 
 # -- per-iteration counters of training (``ModelParallelLDA.counters``)

@@ -11,11 +11,14 @@ Layers, mirroring how the trainer is validated:
   training iterations on the planted-topics corpus, and a zero-count
   snapshot scores exactly the uninformative ceiling V.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.engine.api import ModelParallelLDA
-from repro.core.infer import (FoldInResult, ModelSnapshot, fold_in,
+from repro.core.infer import (FoldInResult, ModelSnapshot,
+                              _fold_in_mh_sweeps, _fold_in_scan_sweeps,
+                              _fold_in_sparse_sweeps, fold_in,
                               init_query_cdk, load_snapshot, pack_queries,
                               theta_from_cdk)
 from repro.core.kvstore import fold_in_oracle
@@ -133,6 +136,56 @@ def test_fold_in_padding_invariance(snap, tiny_corpus, sampler):
     grown = fold_in(snap, word2, mask2, sampler=sampler, z0=z02, u=u2)
     np.testing.assert_array_equal(grown.z[:q, :t], base.z)
     np.testing.assert_array_equal(grown.cdk[:q], base.cdk)
+
+
+def _host_operand_fold_in(snap, sampler, word, mask, z0, u):
+    """Fold-in with every operand converted from the host on the call:
+    the sweeps fed the numpy views of a snapshot that holds no device
+    copy."""
+    k = snap.num_topics
+    cdk0 = init_query_cdk(z0, mask, k)
+    if sampler == "scan":
+        ops = (cdk0, snap.word_term(), word, z0, mask, u, snap.alpha)
+        cdk, z = _fold_in_scan_sweeps(*map(jnp.asarray, ops))
+    elif sampler == "sparse":
+        ops = (cdk0, snap.word_term(), *snap.sparse_state(), word, z0, mask,
+               u)
+        cdk, z = _fold_in_sparse_sweeps(*map(jnp.asarray, ops),
+                                        dcap=min(k, word.shape[1]))
+    else:
+        ops = (cdk0, snap.ckt, snap.ck, snap.ensure_tables(), word, z0,
+               mask, u, snap.alpha, np.float32(snap.beta),
+               np.float32(snap.vbeta))
+        cdk, z = _fold_in_mh_sweeps(*map(jnp.asarray, ops),
+                                    sampler_mode=sampler)
+    cdk = np.asarray(cdk)
+    return cdk, np.asarray(z), theta_from_cdk(cdk, snap.alpha)
+
+
+@pytest.mark.parametrize("sampler", ["scan", "sparse", "mh"])
+def test_resident_snapshot_folds_in_bitwise(snap, tiny_corpus, sampler):
+    """The device-resident snapshot gives the host-operand path's
+    ``cdk``, ``z`` and ``θ`` bit for bit: the device copy holds the host
+    views' bytes, and MH tables built on the device equal the host's."""
+    corpus, _, _ = tiny_corpus
+    _, word, mask, z0, u = _query_arrays(corpus.vocab_size)
+
+    def copy():
+        return ModelSnapshot.from_counts(snap.ckt, snap.ck, snap.alpha,
+                                         snap.beta)
+
+    on_host = copy()
+    want = _host_operand_fold_in(on_host, sampler, word, mask, z0, u)
+    assert on_host.placements == 0
+    resident = copy()
+    TopicInferenceServer(resident, sampler=sampler)       # places it
+    fold_in(resident, word, mask, sampler=sampler, z0=z0, u=u)
+    got = fold_in(resident, word, mask, sampler=sampler, z0=z0, u=u)
+    assert resident.placements == 1
+    assert got.h2d_bytes == sum(
+        x.nbytes for x in (init_query_cdk(z0, mask, K), word, z0, mask, u))
+    for g, w in zip((got.cdk, got.z, got.theta), want):
+        np.testing.assert_array_equal(g, w)
 
 
 def test_fold_in_validation(snap):

@@ -345,6 +345,40 @@ def test_swap_releases_old_servers_once_drained(snap_a, snap_b):
     assert sched.snapshot is snap_b
 
 
+def test_drained_epoch_frees_its_device_copy():
+    """Both snapshots are resident while the old epoch drains; once it
+    has, nothing keeps the old device arrays alive, and the answers
+    stamped with it are still its snapshot's."""
+    import weakref
+
+    import jax
+    v_old = 61                                # a shape no other test uses
+    rng = np.random.default_rng(30)
+    old = ModelSnapshot.from_counts(
+        rng.integers(0, 30, size=(v_old, K)).astype(np.int32))
+    sched = _sched(old, sampler="sparse", num_replicas=2)
+    refs = [weakref.ref(a) for a in old.device_operands("sparse")]
+    docs = [d % v_old for d in _docs(3, seed=31)]
+    pre = [sched.submit(d) for d in docs]
+    new = _snapshot(32)
+    sched.swap_snapshot(new)
+    assert all(r() is not None for r in refs)     # still queued: resident
+    assert new.placements == 1                    # placed at the swap
+    post = [sched.submit(d) for d in docs]
+    sched.drain()
+    assert all(r() is None for r in refs)
+    assert not [a for a in jax.live_arrays() if a.shape[:1] == (v_old,)]
+    for rid in pre:
+        assert sched.results[rid].epoch == 0
+    for rid, d in zip(pre, docs):
+        np.testing.assert_array_equal(sched.results[rid].theta,
+                                      _ref(old, d, sampler="sparse"))
+    for rid, d in zip(post, docs):
+        assert sched.results[rid].epoch == 1
+        np.testing.assert_array_equal(sched.results[rid].theta,
+                                      _ref(new, d, sampler="sparse"))
+
+
 def test_swap_to_identical_snapshot_is_observable(snap_a):
     """Epoch says WHEN, fingerprint says WHAT: swapping in a
     bit-identical model bumps the epoch, keeps the fingerprint, and —

@@ -126,23 +126,49 @@ def _query_bytes(word):
     return q * K * 4 + q * t * (4 + 4 + 1) + SWEEPS * q * t * 4
 
 
+def _fresh(s: ModelSnapshot) -> ModelSnapshot:
+    """A snapshot of the same counts with nothing derived or placed."""
+    return ModelSnapshot.from_counts(s.ckt)
+
+
 @pytest.mark.parametrize("sampler,model_bytes", [
     ("scan", V * K * 4 + K * 4),                   # word term, alpha
     ("sparse", 2 * V * K * 4 + V * 4),             # word term, Xcs, sX
-    # counts, C_k, packed tables [3, V, K], alpha, beta, Vbeta
-    ("mh", V * K * 4 + K * 4 + 3 * V * K * 4 + K * 4 + 4 + 4),
+    # counts, C_k, alpha, beta, Vbeta; the packed tables are built on
+    # the device from the placed counts and cross the link not at all
+    ("mh", V * K * 4 + K * 4 + K * 4 + 4 + 4),
 ])
 def test_fold_in_counts_its_host_inputs(snap, sampler, model_bytes):
+    """The snapshot's bytes are counted once, by the call that places
+    them; every call counts its query's."""
     word, mask = _batch()
-    res = fold_in(snap, word, mask, num_sweeps=SWEEPS, sampler=sampler,
-                  seed=1)
-    assert res.h2d_bytes == model_bytes + _query_bytes(word)
+    fresh = _fresh(snap)
+    first = fold_in(fresh, word, mask, num_sweeps=SWEEPS, sampler=sampler,
+                    seed=1)
+    assert first.h2d_bytes == model_bytes + _query_bytes(word)
+    again = fold_in(fresh, word, mask, num_sweeps=SWEEPS, sampler=sampler,
+                    seed=1)
+    assert again.h2d_bytes == _query_bytes(word)
+    assert (fresh.placements, fresh.placed_bytes) == (1, model_bytes)
+    np.testing.assert_array_equal(first.cdk, again.cdk)
+
+
+def test_host_tables_are_placed_as_they_are(snap):
+    """Word tables already on the host are uploaded, not rebuilt, and
+    the device copy holds their bytes."""
+    fresh = _fresh(snap)
+    tables = fresh.ensure_tables()
+    fresh.device_operands("mh")
+    assert fresh.placed_bytes == 4 * V * K * 4 + 2 * K * 4 + 4 + 4
+    np.testing.assert_array_equal(
+        np.asarray(fresh.device_operands("mh")[2]), tables)
+    assert fresh.placements == 1
 
 
 def test_fold_in_counts_device_arrays_as_zero(snap):
     word, mask = _batch()
-    on_host = fold_in(snap, word, mask, num_sweeps=SWEEPS, seed=1)
-    resident = ModelSnapshot.from_counts(snap.ckt)
+    on_host = fold_in(_fresh(snap), word, mask, num_sweeps=SWEEPS, seed=1)
+    resident = _fresh(snap)
     resident._word_term = jnp.asarray(snap.word_term())
     on_device = fold_in(resident, word, mask, num_sweeps=SWEEPS, seed=1)
     assert on_host.h2d_bytes - on_device.h2d_bytes == V * K * 4
@@ -158,10 +184,17 @@ def _docs(lengths, seed=0):
     return [rng.integers(0, V, size=n).astype(np.int32) for n in lengths]
 
 
+def _bucket_bytes(qb, tb):
+    return qb * K * 4 + qb * tb * (4 + 4 + 1) + SWEEPS * qb * tb * 4
+
+
 def test_batch_log_counters_are_exact(snap):
+    """The snapshot is placed once, at install; a batch sends only its
+    queries."""
     slow = 0.25
+    fresh = _fresh(snap)
     sched = ServingScheduler(
-        snap, sampler="sparse", num_sweeps=SWEEPS, seed=1, max_batch=4,
+        fresh, sampler="sparse", num_sweeps=SWEEPS, seed=1, max_batch=4,
         clock=VirtualClock(10.0), fault_plan=FaultPlan.replica_slow(0, slow))
     plan = [((5, 9, 3), (10.0, 10.1, 10.3), 10.5, (4, 16)),
             ((12, 2), (11.0, 11.0), 11.25, (2, 16))]
@@ -177,9 +210,54 @@ def test_batch_log_counters_are_exact(snap):
         assert entry["t_dispatch"] == t_dispatch
         assert entry["wait_s"] == sum(t_dispatch - t for t in arrivals)
         assert entry["t_finish"] == t_dispatch + slow
-        q = qb * K * 4 + qb * tb * (4 + 4 + 1) + SWEEPS * qb * tb * 4
-        assert entry["h2d_bytes"] == 2 * V * K * 4 + V * 4 + q
+        assert entry["h2d_bytes"] == _bucket_bytes(qb, tb)
     assert sched.stats()["batches"] == 2
+    assert (fresh.placements, fresh.placed_bytes) == \
+        (1, 2 * V * K * 4 + V * 4)
+
+
+def _trace_host_events(tmp_path, fn):
+    """Run ``fn`` under the profiler; the host events it recorded."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [e for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU" for ln in p.lines for e in ln.events]
+
+
+def test_replicas_and_warm_place_the_snapshot_once(snap, tmp_path):
+    """Two replicas and a warm-up share one placement, traced as one
+    ``foldin.place``; every batch after it sends its queries alone."""
+    fresh = _fresh(snap)
+    box = {}
+
+    def serve():
+        sched = ServingScheduler(fresh, sampler="sparse", num_sweeps=SWEEPS,
+                                 seed=1, num_replicas=2, max_batch=4,
+                                 clock=VirtualClock())
+        sched.warm(16)
+        for lengths in ((5, 9, 3), (12, 2), (7,)):
+            for toks in _docs(lengths):
+                sched.submit(toks)
+            sched.tick()
+        box["sched"] = sched
+
+    events = _trace_host_events(tmp_path, serve)
+    sched = box["sched"]
+    model = 2 * V * K * 4 + V * 4
+    place = [e for e in events if e.name == tracing.FOLDIN_PLACE]
+    assert len(place) == 1
+    assert dict(place[0].stats)["bytes"] == model
+    assert (fresh.placements, fresh.placed_bytes) == (1, model)
+    assert [b["replica"] for b in sched.batch_log] == [0, 1, 0]
+    for b in sched.batch_log:
+        assert b["h2d_bytes"] == _bucket_bytes(*b["bucket"])
 
 
 def test_batch_log_is_a_bounded_ring(snap):
@@ -200,6 +278,19 @@ def test_batch_log_is_a_bounded_ring(snap):
 
 FOLDIN = (tracing.FOLDIN_PACK, tracing.FOLDIN_UPLOAD, tracing.FOLDIN_RUN,
           tracing.FOLDIN_FETCH, tracing.FOLDIN_THETA)
+
+
+def test_serving_names_are_pinned():
+    """The benchmark's readers and the span report match these strings
+    letter for letter."""
+    assert (tracing.SERVE_BATCH, tracing.SERVE_DRAWS) == (
+        "serve.batch", "serve.draws")
+    assert (tracing.FOLDIN_PACK, tracing.FOLDIN_PLACE, tracing.FOLDIN_UPLOAD,
+            tracing.FOLDIN_RUN, tracing.FOLDIN_FETCH,
+            tracing.FOLDIN_THETA) == (
+        "foldin.pack", "foldin.place", "foldin.upload", "foldin.run",
+        "foldin.fetch", "foldin.theta")
+    assert tracing.FOLDIN_PLACE in tracing.SPANS
 
 
 def test_one_tick_traces_nested_spans_under_bare_names(snap, tmp_path):
