@@ -6,7 +6,7 @@ inspect the learned topics.
 import numpy as np
 
 from repro.core.metrics import top_words, topic_recovery_score
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 from repro.data.synthetic import synthetic_corpus
 
 # 1. Data: a corpus with 8 planted topics (each owning a word band).

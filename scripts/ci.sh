@@ -27,20 +27,11 @@ PYTHONHASHSEED=0 \
     XLA_FLAGS="--xla_force_host_platform_device_count=4" \
     python -m pytest -x -q -m slow tests/test_mh_stats.py
 
-# Pass 4: end-to-end engine throughput smoke — one tiny workload through
-# benchmarks/bench_e2e.py (table-lifetime A/B on the MH pair, donation
-# assertions, whole-iteration timing), so the e2e benchmark path and the
-# traveling-table engine configuration it exercises can never rot
-# silently.  Smoke mode writes results/bench_e2e_smoke.json only; the
-# recorded perf trajectory (BENCH_e2e.json) is full-mode output.
-python -m benchmarks.bench_e2e --smoke
-
-# Pass 5: train -> snapshot -> serve smoke (DESIGN.md §11).  A 2-iter
+# Pass 4: train -> snapshot -> serve smoke (DESIGN.md §11).  A 2-iter
 # training run exports a frozen snapshot (reporting held-out
-# doc-completion perplexity along the way), lda_infer serves a query
-# batch from it (exits non-zero on non-finite perplexity), and the
-# serving benchmark runs its smoke workload — the full query path from
-# CLI to fold-in kernel exercised on every CI run.
+# doc-completion perplexity along the way) and lda_infer serves a query
+# batch from it (exits non-zero on non-finite perplexity) — the full
+# query path from CLI to fold-in kernel exercised on every CI run.
 SNAP_DIR="$(mktemp -d)"
 python -m repro.launch.lda_infer --queries 6 --query-len 16 --sweeps 3 \
     --docs 48 --vocab 96 --topics 8 --train-iters 2
@@ -49,13 +40,11 @@ python -m repro.launch.lda_train --docs 48 --vocab 96 --topics 8 \
 python -m repro.launch.lda_infer --snapshot "$SNAP_DIR/snap.npz" \
     --queries 8 --query-len 24 --sweeps 3
 rm -rf "$SNAP_DIR"
-python -m benchmarks.bench_infer --smoke
 
-# Pass 6: hybrid sparse family smoke (DESIGN.md §12) — pinned-seed
+# Pass 5: hybrid sparse family smoke (DESIGN.md §12) — pinned-seed
 # 4-device hybrid-grid training with the sparse sampler (train ->
-# snapshot -> sparse fold-in serve through the CLI), then the sparse
-# regime-map benchmark on its tiny CI cell.  Guards the whole §12
-# surface: registry resolution with static sampler args, the 2D
+# snapshot -> sparse fold-in serve through the CLI).  Guards the whole
+# §12 surface: registry resolution with static sampler args, the 2D
 # shard_map path, snapshot sparse state, and the serving alias.
 SPARSE_DIR="$(mktemp -d)"
 PYTHONHASHSEED=0 XLA_FLAGS="--xla_force_host_platform_device_count=4" \
@@ -67,9 +56,8 @@ PYTHONHASHSEED=0 \
     python -m repro.launch.lda_infer --snapshot "$SPARSE_DIR/snap.npz" \
     --sampler sparse --queries 8 --query-len 24 --sweeps 3
 rm -rf "$SPARSE_DIR"
-python -m benchmarks.bench_sparse --smoke
 
-# Pass 7: out-of-core streaming + bit-exact resume smoke (DESIGN.md §13).
+# Pass 6: out-of-core streaming + bit-exact resume smoke (DESIGN.md §13).
 # Shard a corpus to disk, train the streaming engine 2 iterations with
 # per-iteration checkpoints, then "crash" and resume the run to 4
 # iterations from the workdir alone (no corpus flags — geometry, sampler
@@ -89,15 +77,14 @@ python -m repro.launch.lda_infer --snapshot-dir "$STREAM_DIR/snap" \
     --queries 8 --query-len 16 --sweeps 3 --sampler scan
 rm -rf "$STREAM_DIR"
 
-# Pass 8: serving-scheduler traffic-replay smoke (DESIGN.md §14).  Shard
+# Pass 7: serving-scheduler traffic-replay smoke (DESIGN.md §14).  Shard
 # a corpus, train the streaming engine twice to two SHARDED snapshots
 # (earlier + later iterations of one run), then replay a seeded
 # open-loop Poisson trace through lda_serve with a mid-replay hot-swap
 # between them — exits non-zero if any admitted request is dropped, p99
 # is non-finite, or the post-swap epoch never serves.  Both snapshot
 # directories are row-restricted with the SAME word set, so the swap
-# stays a pointer flip.  Then the scheduler benchmark's smoke workload
-# (saturation + latency phases, .npz snapshots, warm-bucket precompile).
+# stays a pointer flip.
 SERVE_DIR="$(mktemp -d)"
 python -m repro.data.stream --out "$SERVE_DIR/corpus" --zipf 1.1 \
     --docs 64 --vocab 128 --doc-len 24 --shards 4 --seed 11
@@ -110,9 +97,8 @@ python -m repro.launch.lda_serve --snapshot-dir "$SERVE_DIR/snapA" \
     --swap-snapshot-dir "$SERVE_DIR/snapB" --swap-after 12 \
     --requests 32 --rate 400 --max-len 16 --sweeps 3 --seed 0
 rm -rf "$SERVE_DIR"
-python -m benchmarks.bench_serve --smoke
 
-# Pass 9: fault-injection + crash-recovery smoke (DESIGN.md §15).  A
+# Pass 8: fault-injection + crash-recovery smoke (DESIGN.md §15).  A
 # reference streaming run trains uninterrupted to 4 iterations; a second
 # run gets a scripted crash (REPRO_FAULT_PLAN kills it at the start of
 # iteration 3, the in-process model of SIGKILL) under
@@ -159,7 +145,7 @@ python -m repro.launch.lda_serve --snapshot-dir "$FT_DIR/snap" \
     --requests 32 --rate 400 --max-len 16 --sweeps 3 --seed 0
 rm -rf "$FT_DIR"
 
-# Pass 10: pluggable CountStore smoke (DESIGN.md §16).  Two streaming
+# Pass 9: pluggable CountStore smoke (DESIGN.md §16).  Two streaming
 # pipelines over the same Zipf corpus — store=dense vs store=tail (K=64
 # so wcap=32 head rows actually occur) — each: train 2 iters with the
 # sparse sampler, checkpoint, resume to 4 iters, export a sharded

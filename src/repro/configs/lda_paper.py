@@ -1,7 +1,7 @@
 """The paper's own workload configs (§5 Experiments).
 
 Not an ``ArchConfig`` — LDA is not a transformer — but registered here so
-the launcher, benchmarks and dry-run can select the paper's exact problem
+the launcher and dry-run can select the paper's exact problem
 sizes by name.
 """
 from __future__ import annotations

@@ -4,7 +4,7 @@ from repro.core.data_parallel import DataParallelLDA
 from repro.core.engine import EngineLayout
 from repro.core.likelihood import log_likelihood
 from repro.core.metrics import delta_error, topic_recovery_score
-from repro.core.model_parallel import ModelParallelLDA, MPState
+from repro.core.engine import ModelParallelLDA, MPState
 from repro.core.schedule import partition_vocab, rotation_permutation
 
 __all__ = [

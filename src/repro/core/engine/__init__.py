@@ -4,18 +4,15 @@ Layout:
 
 * ``state.py``    — :class:`MPState` (slot-queue per-worker state) plus
   layout construction, initialization, and gather/observation helpers;
-* ``rounds.py``   — the shared per-(worker, round) sampling step and the
-  sampler registry both backends draw from;
-* ``backends.py`` — the two bit-identical execution backends
-  (``vmap`` single-device batch, ``shard_map`` one-worker-per-device),
-  generalized to the hybrid 2D ``(data, model)`` grid (DESIGN.md §8);
+* ``rounds.py``   — the per-(worker, round) sampling step and the
+  sampler registry;
+* ``backends.py`` — one per-worker iteration over the hybrid 2D
+  ``(data, model)`` grid (DESIGN.md §8), placed by named-axis ``vmap``
+  on one device or by ``shard_map`` one worker per device;
 * ``reference.py`` — the FROZEN pre-2D 1D implementation, kept only as
   the bit-exactness anchor for ``tests/test_engine_2d.py``; harness-only,
   deliberately NOT re-exported here;
 * ``api.py``      — the :class:`ModelParallelLDA` facade.
-
-``repro.core.model_parallel`` re-exports the public names so pre-package
-imports keep working.
 """
 from repro.core.engine.api import ModelParallelLDA
 from repro.core.engine.backends import (iteration_vmap,

@@ -37,7 +37,7 @@ eq.-(1) acceptance corrects arbitrarily stale proposals — and the host
 oracle mirrors whichever schedule is selected, so replay stays bitwise.
 
 ``track_error=False`` skips the per-round Fig-3 drift statistic (the
-``delta_error()`` history) — benchmarks use it to keep the hot path free
+``delta_error()`` history) — ``bench/`` uses it to keep the hot path free
 of an unconsumed [R, K]-wide reduction per round.
 
 ``data_parallel`` (``D``) is the throughput lever: documents shard

@@ -16,8 +16,8 @@ state out of core:
 
 Peak training memory is therefore bounded by the resident ``[Vb, K]``
 block and one in-flight row/block token group, independent of corpus
-size and of total model size ``V × K`` — the paper's capacity claim,
-measured by ``benchmarks/bench_model_size.py --big``.
+size and of total model size ``V × K`` — the paper's capacity claim
+(unmeasured on the chip).
 
 Bit-exactness.  The scheduler is the serial transcript of the SPMD
 engine — the same frozen-per-round semantics as the host oracle

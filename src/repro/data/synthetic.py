@@ -1,7 +1,7 @@
 """Ground-truth LDA corpus generator.
 
 Samples a corpus from the LDA generative process with known topics so that
-tests/benchmarks can check both likelihood ascent and *recovery* of planted
+tests can check both likelihood ascent and *recovery* of planted
 structure (``metrics.topic_recovery_score``).
 """
 from __future__ import annotations

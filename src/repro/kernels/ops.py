@@ -2,7 +2,7 @@
 
 Handles padding to tile boundaries, platform selection (interpret mode off
 TPU), the word-grouped token layout, and the engine-facing samplers that
-plug into ``core.model_parallel``: ``sweep_block_pallas`` (exact
+plug into ``core.engine``: ``sweep_block_pallas`` (exact
 Gibbs-conditional kernel) and the fused alias-MH cycle pair
 ``sweep_block_mh_pallas`` / ``sweep_block_mh_pallas_tables`` (round vs
 iteration table lifetime, DESIGN.md §10).
@@ -131,7 +131,7 @@ def sweep_block_pallas(cdk, ckt_block, ck, doc, word_off, z, mask, u,
     """Engine-facing sampler: same signature/semantics as
     ``core.sampler.sweep_block_batched`` but with the conditional evaluated
     by the Pallas kernel (token-per-group layout; the word-grouped layout is
-    exercised by ``gibbs_conditional`` directly in benchmarks/tests).
+    exercised by ``gibbs_conditional`` directly in the tests).
 
     Bit-identical to the ``batched`` sampler mode given the same uniforms —
     asserted by tests — so the kernel slots into the model-parallel engine

@@ -3,7 +3,7 @@ production meshes and extract memory/cost/collective analyses.
 
 Usage:
     python -m repro.launch.dryrun --arch olmo-1b --shape train_4k --mesh pod
-    python -m repro.launch.dryrun --all --mesh pod --out benchmarks/results/dryrun
+    python -m repro.launch.dryrun --all --mesh pod --out dryrun_out
     python -m repro.launch.dryrun --all --mesh 2pod   # 512-chip multi-pod pass
 
 This container has ONE real CPU device; the 512 placeholder devices below
@@ -238,7 +238,7 @@ def main() -> None:
     ap.add_argument("--mesh", choices=["pod", "2pod"], default="pod")
     ap.add_argument("--all", action="store_true",
                     help="run every (arch × shape) combination")
-    ap.add_argument("--out", default="benchmarks/results/dryrun")
+    ap.add_argument("--out", default="dryrun_out")
     ap.add_argument("--no-fsdp", action="store_true")
     ap.add_argument("--resume", action="store_true",
                     help="skip combos whose result JSON already exists")

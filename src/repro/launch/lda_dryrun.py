@@ -39,7 +39,7 @@ from repro.roofline import analysis as roofline
 
 
 def run(cfg_name: str, workers: int = 64, sampler: str = "batched",
-        out_dir: str = "benchmarks/results/dryrun",
+        out_dir: str = "dryrun_out",
         blocks_per_worker: int = 1, data_parallel: int = 1) -> dict:
     cfg = LDA_CONFIGS[cfg_name]
     m, k = workers, cfg.num_topics

@@ -130,7 +130,7 @@ def main() -> None:
               f"({snap.ck.sum():,} training tokens)")
         queries = _queries_from_args(args, snap)
     else:
-        from repro.core.model_parallel import ModelParallelLDA
+        from repro.core.engine import ModelParallelLDA
         from repro.data.synthetic import synthetic_corpus
         corpus, _, _ = synthetic_corpus(args.docs, args.vocab, args.topics,
                                         args.doc_len, seed=args.seed)

@@ -31,7 +31,7 @@ from repro.core.data_parallel import DataParallelLDA
 from repro.core.infer import ModelSnapshot
 from repro.core.likelihood import doc_completion_perplexity
 from repro.core.metrics import topic_recovery_score
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 from repro.data.corpus import split_corpus
 from repro.data.synthetic import synthetic_corpus
 from repro.launch.compile_cache import use_compile_cache

@@ -5,33 +5,29 @@ The launch drivers (`lda_train`, `lda_infer`) used to hard-code their
 every CLI.  Choices now come from the engine registry itself
 (`engine/rounds.py`), plus the pseudo-sampler ``auto``:
 
-* ``auto`` picks the sampler FAMILY from the measured regime map
-  (``benchmarks/bench_sparse.py`` full mode, the PR-6 K × doc-len
-  sweep): nearest cell in (log₂ K, log₂ max-doc-len) space decides
-  between ``scan``, ``mh``, and ``sparse`` — the long-tail observation
-  that sparse wins 6/9 cells, MH only the short-K/long-doc corner, and
-  exact scan the mid-K dense cells.  Callers pass the workload's
-  ``num_topics``/``max_doc_len``; without them, ``auto`` falls back to
-  the MH family (the old behaviour).
+* ``auto`` picks the sampler FAMILY from a regime map of K × doc-len
+  cells: nearest cell in (log₂ K, log₂ max-doc-len) space decides
+  between ``scan``, ``mh``, and ``sparse``.  The map was derived from
+  CPU timings and is unmeasured on the chip (ROADMAP design debt 3).
+  Callers pass the workload's ``num_topics``/``max_doc_len``; without
+  them, ``auto`` falls back to the MH family (the old behaviour).
 * ``auto`` then resolves the chosen family per platform: the Pallas
   kernel form on TPU, the jnp twin elsewhere.  The pairs draw
   identically, so the platform leg never changes a chain — only which
   compiled form runs it.
 * Off TPU, an EXPLICITLY requested ``*_pallas`` sampler runs the kernel
   in interpret mode — correct (the bit-identity tests rely on it) but
-  slow at real workload sizes (the repo-root BENCH digest shows
-  ``mh_pallas`` collapsing 208→36 q/s at serving batch 32 on CPU), so
-  the drivers refuse it unless ``--force`` is given.
+  orders of magnitude slower than the jnp twin at real workload sizes,
+  so the drivers refuse it unless ``--force`` is given.
 """
 from __future__ import annotations
 
 import math
 
-# Measured winners of the K × max-doc-len sweep
-# (benchmarks/results/bench_sparse.json, mode="full": Vb=64, 8k tokens,
-# Zipf 1.1).  Keys are the swept (K, doc_len) grid points; lookups snap
-# to the nearest cell in log2 space, since both axes are scale
-# parameters.
+# Winners of a K × max-doc-len sweep timed on the CPU (Vb=64, 8k tokens,
+# Zipf 1.1); unmeasured on the chip (ROADMAP design debt 3).  Keys are
+# the swept (K, doc_len) grid points; lookups snap to the nearest cell in
+# log2 space, since both axes are scale parameters.
 REGIME_MAP = {
     (256, 16): "sparse", (256, 48): "sparse", (256, 256): "mh",
     (4096, 16): "scan", (4096, 48): "sparse", (4096, 256): "scan",
@@ -101,7 +97,7 @@ def resolve_sampler_choice(name: str, *, force: bool = False,
         raise SystemExit(
             f"--sampler {name}: Pallas kernels run in interpret mode on "
             f"{jax.default_backend()!r} — orders of magnitude slower at "
-            f"real sizes (see BENCH_e2e.json). Use --sampler auto, the "
+            f"real sizes. Use --sampler auto, the "
             f"jnp twin {name.removesuffix('_pallas')!r}, or pass --force "
             f"to run interpret mode anyway.")
     return name

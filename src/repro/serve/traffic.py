@@ -1,9 +1,8 @@
 """Seeded serving traffic: Poisson arrivals, heavy-tailed doc lengths,
 and the open-loop replay loop (DESIGN.md §14).
 
-The same trace + replay machinery drives three consumers: the
-deterministic virtual-clock tests (`tests/test_scheduler.py`), the
-wall-clock traffic benchmark (`benchmarks/bench_serve.py`), and the
+The same trace + replay machinery drives two consumers: the
+deterministic virtual-clock tests (`tests/test_scheduler.py`) and the
 ``lda_serve`` CLI.  A trace is a pure function of its seed, so replaying
 it twice — even across processes — submits bit-identical requests at
 identical scheduled times.
@@ -11,9 +10,9 @@ identical scheduled times.
 **Open loop.**  Arrivals follow the SCHEDULE, not the server: a request
 whose scheduled time has passed while the server was busy is submitted
 late but stamped with its scheduled arrival, so queueing delay lands in
-measured latency.  Closed-loop benches (like `bench_infer.py`'s
-back-to-back batches) hide exactly this — the latency a user actually
-sees when the system saturates.
+measured latency.  Closed-loop benches (back-to-back batches) hide
+exactly this — the latency a user actually sees when the system
+saturates.
 """
 from __future__ import annotations
 

@@ -38,7 +38,7 @@ from repro.core.engine import countstore
 from repro.core.engine.countstore import (DEFAULT_TAIL_WCAP, DenseStore,
                                           TailStore, available_stores,
                                           resolve_store)
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 from repro.data.integrity import (CorruptArtifactError, TornWriteError,
                                   flip_byte, truncate_file)
 from repro.data.stream import ShardedCorpus, shard_corpus

@@ -4,7 +4,7 @@ import pytest
 
 from repro.core.counts import check_invariants
 from repro.core.data_parallel import DataParallelLDA, adlda_engine
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 
 
 def test_dp_invariants_and_ascent(tiny_corpus):

@@ -28,7 +28,7 @@ from repro.core.counts import build_counts, check_invariants
 from repro.core.data_parallel import adlda_engine
 from repro.core.engine import reference
 from repro.core.kvstore import HostModelParallelLDA
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 
 STATE_FIELDS = ("cdk", "ckt", "block_id", "ck_synced", "ck_local", "z")
 

@@ -18,7 +18,7 @@ import pytest
 from repro.core import schedule as sched
 from repro.core.counts import build_counts, check_invariants
 from repro.core.kvstore import HostModelParallelLDA
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 from test_model_parallel import _serial_replay
 
 
@@ -153,9 +153,9 @@ def test_resident_block_is_v_over_sm(tiny_corpus, workers, s):
 
 
 def test_backcompat_imports():
-    from repro.core.model_parallel import (  # noqa: F401
-        ModelParallelLDA as A, MPState as B)
-    from repro.core import ModelParallelLDA as C, MPState as D  # noqa: F401
+    """``repro.core`` exports the engine's own facade and state."""
+    from repro.core import ModelParallelLDA as A, MPState as B
+    from repro.core.engine import ModelParallelLDA as C, MPState as D
     assert A is C and B is D
 
 
@@ -168,7 +168,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
 from repro.data.synthetic import synthetic_corpus
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 
 corpus, _, _ = synthetic_corpus(num_docs=40, vocab_size=120, num_topics=8,
                                 doc_len=30, seed=0)

@@ -243,7 +243,7 @@ class TestCheckpointKillMP:
         ("mp_ckpt.promoted", "")])
     def test_kill_mid_checkpoint_resumes_consistent(self, tmp_path, point,
                                                     match):
-        from repro.core.model_parallel import ModelParallelLDA
+        from repro.core.engine import ModelParallelLDA
         corpus = _mp_corpus()
         ref = ModelParallelLDA(corpus, K, W, seed=SEED)
         for _ in range(TOTAL_ITERS):
@@ -313,7 +313,7 @@ class TestPrepareRestart:
         assert sorted(os.listdir(wd)) == [supervise.QUARANTINE_DIR]
 
     def test_mp_tmp_debris_quarantined(self, tmp_path):
-        from repro.core.model_parallel import ModelParallelLDA
+        from repro.core.engine import ModelParallelLDA
         corpus = _mp_corpus()
         wd = str(tmp_path / "wd")
         os.makedirs(wd)
@@ -428,7 +428,7 @@ class TestSupervisedBitwiseRecovery:
 
     @pytest.mark.parametrize("backend", ["vmap", "shard_map"])
     def test_mp_engine_both_backends(self, tmp_path, backend):
-        from repro.core.model_parallel import ModelParallelLDA
+        from repro.core.engine import ModelParallelLDA
         corpus = _mp_corpus()
         ref = ModelParallelLDA(corpus, K, W, seed=SEED, backend=backend)
         for _ in range(TOTAL_ITERS):

@@ -137,7 +137,7 @@ class TestArtifactFamilies:
             sc.load_shard(1)
 
     def test_bit_flipped_mp_checkpoint_rejected(self, tmp_path):
-        from repro.core.model_parallel import ModelParallelLDA
+        from repro.core.engine import ModelParallelLDA
         from repro.data.synthetic import synthetic_corpus
         corpus, _, _ = synthetic_corpus(12, 32, 4, 8, seed=0)
         lda = ModelParallelLDA(corpus, 4, 2, seed=0)

@@ -12,7 +12,7 @@ import pytest
 from repro.core.counts import build_counts, check_invariants
 from repro.core.invindex import scatter_assignments
 from repro.core.metrics import topic_recovery_score
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 from repro.core.sampler import gibbs_sweep_np, sweep_block_scan
 from repro.core import schedule as sched
 

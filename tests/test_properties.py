@@ -9,7 +9,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core.counts import build_counts, check_invariants
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 from repro.data.corpus import bigram_corpus, from_documents, from_texts
 from repro.data.sharding import shard_documents, worker_shard
 from repro.data.synthetic import synthetic_corpus

@@ -14,7 +14,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
 from repro.data.synthetic import synthetic_corpus
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 
 corpus, _, _ = synthetic_corpus(num_docs=40, vocab_size=120, num_topics=8,
                                 doc_len=30, seed=0)

@@ -34,7 +34,7 @@ import pytest
 from repro.core.infer import (fold_in, load_sharded_snapshot_meta,
                               load_snapshot_rows, pack_queries)
 from repro.core.kvstore import HostModelParallelLDA
-from repro.core.model_parallel import ModelParallelLDA
+from repro.core.engine import ModelParallelLDA
 from repro.data.stream import ShardedCorpus, shard_corpus
 from repro.launch.samplers import (REGIME_MAP, regime_sampler,
                                    resolve_sampler_choice)

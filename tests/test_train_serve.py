@@ -94,7 +94,7 @@ def test_checkpoint_roundtrip(tiny_model):
 
 
 def test_checkpoint_lda_state():
-    from repro.core.model_parallel import ModelParallelLDA
+    from repro.core.engine import ModelParallelLDA
     from repro.data.synthetic import synthetic_corpus
     corpus, _, _ = synthetic_corpus(30, 80, 4, 20, seed=0)
     lda = ModelParallelLDA(corpus, 4, 2, seed=0)
